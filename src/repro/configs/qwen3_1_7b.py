@@ -1,10 +1,10 @@
-"""qwen3-1.7b [dense] — qk-norm, GQA kv=8 [hf:Qwen/Qwen3-8B]."""
+"""qwen3-1.7b [dense] — qk-norm, GQA kv=8 [hf:Qwen/Qwen3-1.7B]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
     name="qwen3-1.7b",
     family="dense",
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
     num_layers=28,
     d_model=2048,
     num_heads=16,

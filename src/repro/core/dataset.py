@@ -22,7 +22,7 @@ import numpy as np
 from repro.core.features import records_to_dataset
 from repro.core.profiler import ProfileRecord, profile_workload
 from repro.core.workloads import WorkloadConfig, sample_grid
-from repro.hw import EDGE_DEVICES, get_device
+from repro.hw import EDGE_DEVICES, DeviceSpec, local_device_spec
 
 
 def generate(n_runs: int = 120, *, max_steps: int = 8, seed: int = 0,
@@ -30,7 +30,7 @@ def generate(n_runs: int = 120, *, max_steps: int = 8, seed: int = 0,
              verbose: bool = False):
     """Returns (records, TabularDataset)."""
     grid = sample_grid(n_runs, seed=seed)
-    base_dev = get_device("xps15-i5")
+    base_dev = local_device_spec()
     records: list[ProfileRecord] = []
     t0 = time.time()
     for i, wc in enumerate(grid):
@@ -41,13 +41,14 @@ def generate(n_runs: int = 120, *, max_steps: int = 8, seed: int = 0,
             print(f"[dataset] {i+1}/{len(grid)} runs "
                   f"({time.time()-t0:.0f}s)")
     if augment_hardware:
-        records += project_hardware(records)
+        records += project_hardware(records, base_dev)
     return records, records_to_dataset(records)
 
 
-def project_hardware(records: list[ProfileRecord]) -> list[ProfileRecord]:
-    """Analytic re-projection of measured runs onto other device specs."""
-    base = get_device("xps15-i5")
+def project_hardware(records: list[ProfileRecord], base: DeviceSpec
+                     ) -> list[ProfileRecord]:
+    """Analytic re-projection of runs measured on ``base`` onto the
+    other edge-device specs."""
     out = []
     for rec in records:
         for name, dev in EDGE_DEVICES.items():
@@ -64,6 +65,7 @@ def project_hardware(records: list[ProfileRecord]) -> list[ProfileRecord]:
                 total_time_s=rec.total_time_s * scale,
                 step_time_s=rec.step_time_s * scale,
                 hardware=dev.as_features(),
+                device=name,
             ))
     return out
 

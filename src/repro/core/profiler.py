@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core.workloads import (WorkloadConfig, init_workload_params,
                                   synthetic_image_data, workload_loss)
-from repro.hw import DeviceSpec, get_device
+from repro.hw import DeviceSpec, local_device_spec
 from repro.optim import apply_updates, get_optimizer
 
 
@@ -45,6 +45,7 @@ class ProfileRecord:
     # --- inputs (features) ---
     config: dict
     hardware: dict
+    device: str = ""            # DeviceSpec.name the run was measured on
 
     def targets(self, extended: bool = False) -> dict:
         """Prediction targets.  The default three are the paper's Fig. 3
@@ -81,12 +82,16 @@ def profile_workload(wc: WorkloadConfig, *, device: Optional[DeviceSpec] = None,
                      seed: int = 0) -> ProfileRecord:
     """Train the Table-I workload and record its profile.
 
+    ``device`` defaults to the spec of the device JAX runs on
+    (:func:`repro.hw.local_device_spec`), so a record names the hardware
+    that produced it.
+
     ``max_steps`` > 0 truncates the run and extrapolates total time from the
     measured per-step time (the profiling-dataset generator uses this to
     keep >100-run grids tractable; the benchmark validates the
     extrapolation error on full runs).
     """
-    device = device or get_device("xps15-i5")
+    device = device or local_device_spec()
     key = jax.random.key(seed)
     params = init_workload_params(wc, key)
     opt = get_optimizer(wc.optimiser, wc.lr)
@@ -154,4 +159,5 @@ def profile_workload(wc: WorkloadConfig, *, device: Optional[DeviceSpec] = None,
         final_acc=acc_v,
         config=dataclasses.asdict(wc),
         hardware=device.as_features(),
+        device=device.name,
     )

@@ -135,6 +135,30 @@ EDGE_DEVICES: dict[str, DeviceSpec] = {
 
 ALL_DEVICES: dict[str, DeviceSpec] = {**EDGE_DEVICES, TPU_V5E.name: TPU_V5E}
 
+#: ``jax.Device.device_kind`` → the spec of the device that reports it.
+#: A CPU run stands in for the paper's own testbed, the XPS 15 laptop
+#: (§III-A): this entry is the one place that choice is made.
+DEVICE_KINDS: dict[str, DeviceSpec] = {
+    "cpu": XPS15_I5,
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def spec_for_kind(device_kind: str) -> DeviceSpec:
+    """The spec of a JAX device kind; an unknown kind is an error."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError as e:
+        raise KeyError(
+            f"no DeviceSpec for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_KINDS)} (add the chip to hw.specs)") from e
+
+
+def local_device_spec() -> DeviceSpec:
+    """The spec of the device JAX runs on here (its first device)."""
+    import jax
+    return spec_for_kind(jax.devices()[0].device_kind)
+
 
 def get_device(name: str) -> DeviceSpec:
     try:

@@ -30,7 +30,7 @@ Bit-for-bit notes (why this file looks the way it does):
     (analytic and predictor-driven) have no mul→add chain and stay
     fully jitted; lowered tree-model inference is add-only (leaf values
     pre-scaled on the host), so it too stays bit-for-bit under jit.
-  * Everything executes in f64 under ``jax.experimental.enable_x64`` so
+  * Everything executes in f64 under ``repro.x64.x64`` so
     host and accelerator decisions are interchangeable; the Pallas path
     runs the kernel in f32 (the TPU-native width) and re-evaluates the
     chosen splits in f64 on the host — O(E) gathers, no matrices.
@@ -45,12 +45,12 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.costs import (ACCEL_OBJECTIVES, AccelSpec, lower_to_accel,
                               scalarize_weighted)
 from repro.core.decisions import DecisionPlan, EnvArrays
 from repro.core.offload import DEFAULT_EFFICIENCY, LayerCost
+from repro.x64 import x64
 
 def _layer_arrays(layers: Sequence[LayerCost]):
     n = len(layers)
@@ -242,7 +242,7 @@ def _decide_jax(layers, flops, act, env_arrs, spec: AccelSpec, cost):
     # queue-wait / tail objectives take the eager extended path; when
     # both are off the historical branches run untouched (bit-for-bit)
     queued = (spec.queue_wait_s != 0.0 or len(spec.objectives) > 4)
-    with enable_x64():
+    with x64():
         if spec.lowered is not None:
             t_dev, t_edge = spec.lowered.times(layers)
             pargs = tuple(jnp.asarray(x) for x in
@@ -385,7 +385,7 @@ def latency_matrix_jax(layers: Sequence[LayerCost], envs: EnvArrays,
     ``decisions.latency_matrix`` — the equivalence-test surface."""
     flops, act = _layer_arrays(layers)
     dev, edge, bw, lat, inp, _, _ = _env_arrays(envs)
-    with enable_x64():
+    with x64():
         dev_cum, xfer, edge_cum, _ = _latency_parts(
             *(jnp.asarray(x) for x in (flops, act, dev, edge, bw, lat,
                                        inp)), efficiency)

@@ -15,6 +15,11 @@ accumulate in the output block across the sequential tree axis of the
 grid, and the ``[N, n_trees]`` per-tree prediction matrix is never
 materialised.
 
+The node arrays go to the kernel as ``[T, 1, M]`` with the tree axis
+squeezed out of the block: each block is then one whole ``[1, M]`` row,
+which the TPU lowering accepts for any ``M`` (a ``(1, M)`` block over a
+``[T, M]`` array is refused unless ``T == 1``).
+
 VMEM per step: codes block (blk × F int32) + 5 node rows + the
 ``[blk, max_nodes]`` one-hot transient + out (blk × 1) ≈ 1–2 MB at
 blk=512, F ≤ 32, max_nodes ≤ 256.
@@ -92,7 +97,11 @@ def tree_predict_kernel(codes, feature, threshold_bin, left, right,
     nb = (n + pad) // blk
     kernel = functools.partial(_kernel, max_depth=max_depth,
                                max_nodes=max_nodes, n_feat=f)
-    tree_spec = pl.BlockSpec((1, max_nodes), lambda ir, it: (it, 0))
+    tree_spec = pl.BlockSpec((pl.squeezed, 1, max_nodes),
+                             lambda ir, it: (it, 0, 0))
+    feature, threshold_bin, left, right, scaled_value = (
+        x[:, None, :] for x in (feature, threshold_bin, left, right,
+                                scaled_value))
     out = pl.pallas_call(
         kernel,
         grid=(nb, n_trees),

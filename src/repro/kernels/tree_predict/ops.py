@@ -21,9 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-
 from repro.kernels.tree_predict.ref import TreeArrays
+from repro.x64 import x64
 
 
 def _bin_codes(x, edges):
@@ -88,7 +87,7 @@ def predict_trees(x: np.ndarray, arrays: TreeArrays, *,
     if backend != "jax":
         raise ValueError(f"unknown tree-predict backend {backend!r}; "
                          "expected 'jax' or 'pallas'")
-    with enable_x64():
+    with x64():
         fn = getattr(arrays, "_jitted", None)
         if fn is None:
             # learning_rate folded into the leaf values host-side, in

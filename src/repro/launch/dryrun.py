@@ -198,6 +198,8 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="JSON output path")
     args = ap.parse_args()
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     archs = ARCH_NAMES if (args.all or not args.arch) else [args.arch]
     shapes = list(INPUT_SHAPES) if (args.all or not args.shape) \
         else [args.shape]

@@ -1,7 +1,11 @@
-"""Serving launcher: batched requests against a (reduced) model.
+"""Serving launcher: batched requests against a model.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
-        --requests 8 --max-new 16
+        --requests 8 --max-new 16 [--reduced]
+
+The full config at its own dtype unless ``--reduced`` is given (the
+family-faithful small config in float32, for a CPU host) — the
+convention of ``repro.launch.train``.
 """
 import argparse
 
@@ -15,12 +19,16 @@ def main() -> None:
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
 
-    from repro.configs import reduced_config
+    from repro.configs import get_config, reduced_config
+    from repro.launch.cache import use_compile_cache
     from repro.serve import Request, ServeEngine
 
-    cfg = reduced_config(args.arch).replace(dtype="float32")
+    use_compile_cache()
+    cfg = (reduced_config(args.arch).replace(dtype="float32")
+           if args.reduced else get_config(args.arch))
     engine = ServeEngine(cfg, batch_size=args.batch_size,
                          max_len=args.prompt_len + args.max_new + 8)
     rng = np.random.default_rng(0)

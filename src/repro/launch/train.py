@@ -27,11 +27,12 @@ def main() -> None:
     if args.debug_mesh:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
-    import jax
-
     from repro.configs import get_config, reduced_config
     from repro.configs.base import InputShape
+    from repro.launch.cache import use_compile_cache
     from repro.train import TrainConfig, train
+
+    use_compile_cache()
 
     cfg = (reduced_config(args.arch) if args.reduced
            else get_config(args.arch))

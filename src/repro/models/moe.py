@@ -145,10 +145,6 @@ def moe_mlp(params, x: jax.Array, cfg):
 
     if (mesh is not None and tp_size > 1 and e % tp_size == 0):
         from jax.sharding import PartitionSpec as P
-        try:                             # jax >= 0.5
-            shard_map = jax.shard_map
-        except AttributeError:           # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
         tok_dp = dp if (t % max(dp_size, 1) == 0 and dp_size > 1) else ()
         t_local = t // dp_size if tok_dp else t
         n_local = e // tp_size
@@ -164,15 +160,11 @@ def moe_mlp(params, x: jax.Array, cfg):
             # returns x.dtype
             return jax.lax.psum(y, "model")
 
-        import inspect
-        check_kw = ("check_vma" if "check_vma"
-                    in inspect.signature(shard_map).parameters
-                    else "check_rep")    # pre-0.5 jax spelling
-        y = shard_map(
+        y = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
             out_specs=tok_spec,
-            **{check_kw: False},
+            check_vma=False,
         )(x_flat, idx, weights, params["w_gate"], params["w_up"],
           params["w_down"])
     else:

@@ -69,8 +69,8 @@ class LoweredLinear(LoweredPredictor):
     def predict(self, x: np.ndarray, *, backend: str = "jax") -> np.ndarray:
         _require_jax_backend(self, backend)
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
-        with enable_x64():
+        from repro.x64 import x64
+        with x64():
             xs = (jnp.asarray(np.asarray(x, np.float64))
                   - jnp.asarray(self.x_mu)) / jnp.asarray(self.x_sd)
             xs = jnp.concatenate(

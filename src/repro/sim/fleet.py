@@ -45,6 +45,7 @@ sequential features are rejected rather than silently diverging:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 from typing import Optional, Sequence
 
@@ -110,16 +111,13 @@ def _place_singleton_run(avail, peak_eff, ts, fls, ibs, bwc_rows, segs):
     per-slab bandwidth table and ``segs`` the per-task row index — the
     row gather happens as a dynamic slice *inside* the scan, so the
     ``[n, N]`` expansion never materialises on the host.  Returns
-    ``(j, start, finish, etc)`` host arrays, or ``None`` if jax is
-    unavailable (callers fall back to the Python loop)."""
-    try:
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-    except ImportError:                  # pragma: no cover - jax baked in
-        return None
+    ``(j, start, finish, etc)`` host arrays."""
+    import jax.numpy as jnp
+
+    from repro.x64 import x64
     n = len(ts)
     outs: list[tuple] = []
-    with enable_x64():
+    with x64():
         fn = _singleton_scan(avail.shape[0])
         av = jnp.asarray(avail)
         pk = jnp.asarray(peak_eff)
@@ -145,24 +143,49 @@ def _place_singleton_run(avail, peak_eff, ts, fls, ibs, bwc_rows, segs):
     return tuple(np.concatenate([o[k] for o in outs]) for k in range(4))
 
 
+@functools.lru_cache(maxsize=8)
+def sharded_latency_fn(mesh, efficiency: float = DEFAULT_EFFICIENCY):
+    """The jitted latency decide with its environment axis sharded over
+    every axis of ``mesh``: ``(flops, act, dev, edge, bw, lat, inp) ->
+    (split, total, dev, xfer, edge)``.  Layer rows are replicated; the
+    env count must divide by the mesh size (:func:`decide_all_sharded`
+    pads).  Call it under :func:`repro.x64.x64`.  Memoised per (mesh,
+    efficiency), so repeated sweeps reuse one compiled program."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.decide_split import ops
+
+    env_spec = P(tuple(mesh.axis_names))              # env axis over all
+    return jax.jit(jax.shard_map(
+        lambda f, a, d, e, b, l, i: ops._decide_latency(
+            f, a, d, e, b, l, i, efficiency),
+        mesh=mesh,
+        in_specs=(P(), P()) + (env_spec,) * 5,
+        out_specs=(env_spec,) * 5,
+        check_vma=False))
+
+
 def decide_all_sharded(layers, envs: dec.EnvArrays,
                        efficiency: float = DEFAULT_EFFICIENCY, *,
                        mesh=None) -> dec.DecisionPlan:
     """``decide_all`` with the environment axis sharded across devices.
 
-    Wraps the jitted latency kernel in ``shard_map`` over ``mesh``
-    (default: the repo's debug mesh over every visible device; a single
-    device falls back to the plain jit path), padding the env axis to
-    the shard count with :func:`repro.core.decisions.pad_envs` and
-    trimming the results.  The maths is row-wise, so the result is
-    bit-for-bit (f64) the numpy/jax ``decide_all``.
+    Runs :func:`sharded_latency_fn` over ``mesh`` (default: the repo's
+    debug mesh over every visible device; a single device falls back to
+    the plain jit path), with the env arrays placed shard by shard, the
+    env axis padded to the shard count with
+    :func:`repro.core.decisions.pad_envs` and the results trimmed.  The
+    maths is row-wise, so the result is bit-for-bit (f64) the numpy/jax
+    ``decide_all`` wherever f64 is native.
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.decide_split import ops
+    from repro.x64 import x64
 
     if mesh is None:
         n_dev = jax.device_count()
@@ -170,36 +193,17 @@ def decide_all_sharded(layers, envs: dec.EnvArrays,
             return dec.decide_all(layers, envs, efficiency, backend="jax")
         from repro.launch.mesh import make_debug_mesh
         mesh = make_debug_mesh(n_dev)
-    try:
-        shard_map = jax.shard_map                    # jax >= 0.5
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    check_kw = ("check_vma" if "check_vma"
-                in inspect.signature(shard_map).parameters
-                else "check_rep")                    # pre-0.5 spelling
-    axes = tuple(mesh.axis_names)
-    n_shards = 1
-    for a in axes:
-        n_shards *= int(mesh.shape[a])
-    padded, n_orig = dec.pad_envs(envs, n_shards)
+    padded, n_orig = dec.pad_envs(envs, mesh.size)
     flops, act = ops._layer_arrays(layers)
     dev, edge, bw, lat, inp, _, _ = ops._env_arrays(padded)
-    env_spec = P(axes)                               # env axis over all
-    with enable_x64():
-        fn = shard_map(
-            lambda f, a, d, e, b, l, i: ops._decide_latency(
-                f, a, d, e, b, l, i, efficiency),
-            mesh=mesh,
-            in_specs=(P(), P(), env_spec, env_spec, env_spec, env_spec,
-                      env_spec),
-            out_specs=(env_spec,) * 5,
-            **{check_kw: False})
-        s, total, dev_s, xfer_s, edge_s = fn(
-            *(jnp.asarray(x) for x in (flops, act, dev, edge, bw, lat,
-                                       inp)))
+    rows = NamedSharding(mesh, P())
+    env_axis = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    with x64():
+        args = [jax.device_put(jnp.asarray(x), rows) for x in (flops, act)]
+        args += [jax.device_put(jnp.asarray(x), env_axis)
+                 for x in (dev, edge, bw, lat, inp)]
         out = [np.asarray(x)[:n_orig]
-               for x in (s, total, dev_s, xfer_s, edge_s)]
+               for x in sharded_latency_fn(mesh, efficiency)(*args)]
     return dec.DecisionPlan(np.asarray(out[0], np.int64),
                             *(np.asarray(x, np.float64)
                               for x in out[1:]))
@@ -444,14 +448,13 @@ def simulate_fleet(tasks: Sequence[sch.Task], arrivals,
                 res = _place_singleton_run(
                     avail, peak_eff, batch_times[bi:end], flops_t[rids],
                     ib_t[rids], bwc_rows, seg_etc[bi:end])
-                if res is not None:
-                    sl = slice(pos, pos + (end - bi))
-                    p_rid[sl] = rids
-                    p_j[sl], p_start[sl], p_fin[sl], p_etc[sl] = res
-                    p_seg[sl] = seg_of_batch[bi:end]
-                    pos += end - bi
-                    bi = end
-                    continue
+                sl = slice(pos, pos + (end - bi))
+                p_rid[sl] = rids
+                p_j[sl], p_start[sl], p_fin[sl], p_etc[sl] = res
+                p_seg[sl] = seg_of_batch[bi:end]
+                pos += end - bi
+                bi = end
+                continue
         t = float(batch_times[bi])
         members = order[offsets[bi]:offsets[bi + 1]]
         s = int(seg_of_batch[bi])

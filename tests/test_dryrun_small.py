@@ -44,7 +44,8 @@ print(json.dumps(out))
 def test_dryrun_pipeline_on_debug_mesh():
     res = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                          text=True, timeout=540,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                              "JAX_PLATFORMS": "cpu"})
     assert res.returncode == 0, res.stderr[-2000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert set(out) == {"qwen3-1.7b", "deepseek-moe-16b", "zamba2-1.2b"}

@@ -214,7 +214,7 @@ def test_fleet_scan_path_equivalence(policy, ground_truth, cnn_layers,
 
     def counting(*a, **k):
         res = real(*a, **k)
-        calls.append(res is not None)
+        calls.append(len(res[0]))           # placements the scan made
         return res
 
     monkeypatch.setattr(fleet_mod, "_place_singleton_run", counting)
@@ -520,7 +520,7 @@ def test_decide_all_sharded_eight_devices():
     r = subprocess.run(
         [sys.executable, "-c", _SHARDED_SCRIPT], capture_output=True,
         text=True, cwd=str(Path(__file__).resolve().parent.parent),
-        env={**__import__("os").environ,
+        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": str(Path(__file__).resolve().parent.parent
                                / "src")})
     assert r.returncode == 0, r.stderr[-2000:]
