@@ -75,6 +75,7 @@ import numpy as np
 from repro.core.offload import (DEFAULT_EFFICIENCY as EFFICIENCY, LayerCost,
                                 OffloadEnv, SplitDecision)
 from repro.hw import DeviceSpec
+from repro.obs.trace import region
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,19 +106,20 @@ def make_envs(device, edge, link_bw,
     """Broadcast scalars/vectors of specs and link states into an
     :class:`EnvArrays`.  ``device``/``edge`` may be a single
     :class:`DeviceSpec` or a sequence of them."""
-    arrs = np.broadcast_arrays(
-        np.atleast_1d(np.asarray(_spec_attr(device, "peak_flops_f32"),
-                                 np.float64)),
-        np.atleast_1d(np.asarray(_spec_attr(edge, "peak_flops_f32"),
-                                 np.float64)),
-        np.atleast_1d(np.asarray(link_bw, np.float64)),
-        np.atleast_1d(np.asarray(link_latency_s, np.float64)),
-        np.atleast_1d(np.asarray(input_bytes, np.float64)),
-        np.atleast_1d(np.asarray(_spec_attr(device, "tdp_watts"),
-                                 np.float64)),
-        np.atleast_1d(np.asarray(_spec_attr(edge, "tdp_watts"),
-                                 np.float64)))
-    return EnvArrays(*arrs)
+    with region("decide", "envs"):
+        arrs = np.broadcast_arrays(
+            np.atleast_1d(np.asarray(_spec_attr(device, "peak_flops_f32"),
+                                     np.float64)),
+            np.atleast_1d(np.asarray(_spec_attr(edge, "peak_flops_f32"),
+                                     np.float64)),
+            np.atleast_1d(np.asarray(link_bw, np.float64)),
+            np.atleast_1d(np.asarray(link_latency_s, np.float64)),
+            np.atleast_1d(np.asarray(input_bytes, np.float64)),
+            np.atleast_1d(np.asarray(_spec_attr(device, "tdp_watts"),
+                                     np.float64)),
+            np.atleast_1d(np.asarray(_spec_attr(edge, "tdp_watts"),
+                                     np.float64)))
+        return EnvArrays(*arrs)
 
 
 def stack_envs(envs: Sequence[OffloadEnv]) -> EnvArrays:
@@ -247,45 +249,46 @@ def decide_all(layers: Sequence[LayerCost], envs: EnvArrays,
     (``repro.oracle.lowered``) and only raises when the wrapped model
     has no array form.
     """
-    if cost is not None and efficiency != EFFICIENCY:
-        raise ValueError(
-            "efficiency= is ignored when cost= is given; set it on the "
-            "cost model instead (e.g. AnalyticCost(efficiency=...))")
-    if backend != "numpy":
-        from repro.kernels.decide_split import ops
-        return ops.decide_accel(layers, envs, efficiency, cost=cost,
-                                backend=backend)
-    if cost is None:
-        dev_cum, xfer, edge_cum = latency_components(layers, envs,
-                                                     efficiency)
-        total = dev_cum + xfer + edge_cum
-        s = np.argmin(total, axis=1)
-        rows = np.arange(len(envs))
-        return DecisionPlan(s, total[rows, s], dev_cum[rows, s],
-                            xfer[rows, s], edge_cum[rows, s])
-    comp = np.asarray(cost.components(layers, envs), np.float64)
-    scalar = cost.scalarize(comp)
-    s = np.argmin(scalar, axis=1)
-    rows = np.arange(comp.shape[0])
-    objectives = tuple(cost.objectives)
-    comp_s = comp[rows, s]
-    if "latency_s" in objectives:
-        total = comp_s[:, objectives.index("latency_s")]
-    else:
-        # no latency objective -> the scalarised weighted cost is in
-        # arbitrary units, not seconds; total_time_s must not lie
-        # (scalar_cost below still carries the value the argmin ranked by)
-        total = np.full(len(rows), np.nan)
-    parts_fn = getattr(cost, "latency_parts", None)
-    if parts_fn is not None:
-        dev_cum, xfer, edge_cum = parts_fn(layers, envs)
-        dev_t, xfer_t, edge_t = (dev_cum[rows, s], xfer[rows, s],
-                                 edge_cum[rows, s])
-    else:                            # no latency decomposition available
-        dev_t = xfer_t = edge_t = np.full(len(rows), np.nan)
-    return DecisionPlan(s, total, dev_t, xfer_t, edge_t,
-                        objectives=objectives, components=comp_s,
-                        scalar_cost=scalar[rows, s])
+    with region("decide", "call"):
+        if cost is not None and efficiency != EFFICIENCY:
+            raise ValueError(
+                "efficiency= is ignored when cost= is given; set it on the "
+                "cost model instead (e.g. AnalyticCost(efficiency=...))")
+        if backend != "numpy":
+            from repro.kernels.decide_split import ops
+            return ops.decide_accel(layers, envs, efficiency, cost=cost,
+                                    backend=backend)
+        if cost is None:
+            dev_cum, xfer, edge_cum = latency_components(layers, envs,
+                                                         efficiency)
+            total = dev_cum + xfer + edge_cum
+            s = np.argmin(total, axis=1)
+            rows = np.arange(len(envs))
+            return DecisionPlan(s, total[rows, s], dev_cum[rows, s],
+                                xfer[rows, s], edge_cum[rows, s])
+        comp = np.asarray(cost.components(layers, envs), np.float64)
+        scalar = cost.scalarize(comp)
+        s = np.argmin(scalar, axis=1)
+        rows = np.arange(comp.shape[0])
+        objectives = tuple(cost.objectives)
+        comp_s = comp[rows, s]
+        if "latency_s" in objectives:
+            total = comp_s[:, objectives.index("latency_s")]
+        else:
+            # no latency objective -> the scalarised weighted cost is in
+            # arbitrary units, not seconds; total_time_s must not lie
+            # (scalar_cost below still carries the value the argmin ranked by)
+            total = np.full(len(rows), np.nan)
+        parts_fn = getattr(cost, "latency_parts", None)
+        if parts_fn is not None:
+            dev_cum, xfer, edge_cum = parts_fn(layers, envs)
+            dev_t, xfer_t, edge_t = (dev_cum[rows, s], xfer[rows, s],
+                                     edge_cum[rows, s])
+        else:                            # no latency decomposition available
+            dev_t = xfer_t = edge_t = np.full(len(rows), np.nan)
+        return DecisionPlan(s, total, dev_t, xfer_t, edge_t,
+                            objectives=objectives, components=comp_s,
+                            scalar_cost=scalar[rows, s])
 
 
 def pad_envs(envs: EnvArrays, multiple: int) -> tuple[EnvArrays, int]:

@@ -50,6 +50,7 @@ from repro.core.costs import (ACCEL_OBJECTIVES, AccelSpec, lower_to_accel,
                               scalarize_weighted)
 from repro.core.decisions import DecisionPlan, EnvArrays
 from repro.core.offload import DEFAULT_EFFICIENCY, LayerCost
+from repro.obs.trace import region
 from repro.x64 import x64
 
 def _layer_arrays(layers: Sequence[LayerCost]):
@@ -242,7 +243,7 @@ def _decide_jax(layers, flops, act, env_arrs, spec: AccelSpec, cost):
     # queue-wait / tail objectives take the eager extended path; when
     # both are off the historical branches run untouched (bit-for-bit)
     queued = (spec.queue_wait_s != 0.0 or len(spec.objectives) > 4)
-    with x64():
+    with region("decide", "jax"), x64():
         if spec.lowered is not None:
             t_dev, t_edge = spec.lowered.times(layers)
             pargs = tuple(jnp.asarray(x) for x in
@@ -276,38 +277,51 @@ def _decide_pallas(layers, flops, act, env_arrs, spec: AccelSpec, cost,
                                                    pack_spec)
     dev, edge, bw, lat, inp, dev_w, edge_w = env_arrs
     n = flops.shape[0]
-    bvec = np.concatenate(([0.0], act))
-    bvec[-1] = 0.0                                       # split == L
-    if spec.lowered is not None:
-        # predictor mode: prefix sums of the lowered per-layer times,
-        # unit divisors (the rows already are seconds)
-        t_dev, t_edge = spec.lowered.times(layers)
-        dcum = np.concatenate(([0.0], np.cumsum(t_dev)))
-        ecum = np.concatenate(([0.0], np.cumsum(t_edge)))
-        dev_div = np.ones_like(dev)
-        edge_div = np.ones_like(edge)
-    else:
-        fcum = np.concatenate(([0.0], np.cumsum(flops)))  # [L+1] f64
-        dcum = ecum = fcum
-        dev_div = dev * spec.efficiency
-        edge_div = edge * spec.efficiency
-    etot = float(ecum[-1])
-    spec_vec = pack_spec(spec.weights,
-                         radio_watts=spec.radio_watts,
-                         price_per_edge_s=spec.price_per_edge_s,
-                         price_per_gb=spec.price_per_gb,
-                         deadline_s=spec.deadline_s, edge_total=etot,
-                         queue_wait_s=spec.queue_wait_s,
-                         tail_excess_s=spec.tail_excess_s,
-                         tail_weight=spec.tail_weight)
-    f32 = [jnp.asarray(x, jnp.float32)
-           for x in (dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp,
-                     dev_w, edge_w)]
-    s, _ = decide_split_kernel(*f32, jnp.asarray(spec_vec),
-                               block_e=block_e, block_s=block_s,
-                               interpret=interpret)
-    s = np.asarray(s, np.int64)
-    # exact f64 costs at the kernel-chosen splits: O(E) gathers, no [E, S]
+    with region("decide", "prep"):
+        bvec = np.concatenate(([0.0], act))
+        bvec[-1] = 0.0                                   # split == L
+        if spec.lowered is not None:
+            # predictor mode: prefix sums of the lowered per-layer times,
+            # unit divisors (the rows already are seconds)
+            t_dev, t_edge = spec.lowered.times(layers)
+            dcum = np.concatenate(([0.0], np.cumsum(t_dev)))
+            ecum = np.concatenate(([0.0], np.cumsum(t_edge)))
+            dev_div = np.ones_like(dev)
+            edge_div = np.ones_like(edge)
+        else:
+            fcum = np.concatenate(([0.0], np.cumsum(flops)))  # [L+1] f64
+            dcum = ecum = fcum
+            dev_div = dev * spec.efficiency
+            edge_div = edge * spec.efficiency
+        etot = float(ecum[-1])
+        spec_vec = pack_spec(spec.weights,
+                             radio_watts=spec.radio_watts,
+                             price_per_edge_s=spec.price_per_edge_s,
+                             price_per_gb=spec.price_per_gb,
+                             deadline_s=spec.deadline_s, edge_total=etot,
+                             queue_wait_s=spec.queue_wait_s,
+                             tail_excess_s=spec.tail_excess_s,
+                             tail_weight=spec.tail_weight)
+    with region("decide", "h2d"):
+        f32 = [jnp.asarray(x, jnp.float32)
+               for x in (dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp,
+                         dev_w, edge_w)]
+        spec_arr = jnp.asarray(spec_vec)
+    with region("decide", "kernel"):
+        s, _ = decide_split_kernel(*f32, spec_arr, block_e=block_e,
+                                   block_s=block_s, interpret=interpret)
+    with region("decide", "sync"):
+        s = np.asarray(s, np.int64)
+    with region("decide", "reeval"):
+        return _reevaluate(s, n, dcum, ecum, etot, bvec, dev_div, edge_div,
+                           env_arrs, spec, cost)
+
+
+def _reevaluate(s, n, dcum, ecum, etot, bvec, dev_div, edge_div, env_arrs,
+                spec: AccelSpec, cost) -> DecisionPlan:
+    """Exact f64 costs at the kernel-chosen splits ``s``: O(E) gathers,
+    no ``[E, S]`` matrix."""
+    _, _, bw, lat, inp, dev_w, edge_w = env_arrs
     dev_s = dcum[s] / dev_div
     edge_s = (etot - ecum[s]) / edge_div
     ship = np.where(s == n, 0.0, np.where(s == 0, inp, bvec[s]))

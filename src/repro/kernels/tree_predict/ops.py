@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from repro.kernels.tree_predict.ref import TreeArrays
+from repro.obs.trace import region
 from repro.x64 import x64
 
 
@@ -73,21 +74,27 @@ def predict_trees(x: np.ndarray, arrays: TreeArrays, *,
     """``[N]`` f64 predictions for ``x [N, F]`` — the accelerated twin of
     ``GBTRegressor.predict`` (bit-for-bit on ``backend="jax"``, within
     f32 tolerance on ``backend="pallas"``)."""
-    x32 = np.asarray(x, np.float32)
     if backend == "pallas":
         from repro.kernels.tree_predict.kernel import tree_predict_kernel
-        codes = _bin_codes(jnp.asarray(x32), jnp.asarray(arrays.edges))
-        out = tree_predict_kernel(
-            jnp.asarray(codes, jnp.int32),
-            jnp.asarray(arrays.feature), jnp.asarray(arrays.threshold_bin),
-            jnp.asarray(arrays.left), jnp.asarray(arrays.right),
-            jnp.asarray(arrays.learning_rate * arrays.value, jnp.float32),
-            max_depth=arrays.max_depth, blk=blk, interpret=interpret)
-        return np.asarray(out, np.float64) + arrays.base
+        with region("predict", "bin"):
+            codes = _bin_codes(jnp.asarray(np.asarray(x, np.float32)),
+                               jnp.asarray(arrays.edges))
+        with region("predict", "kernel"):
+            out = tree_predict_kernel(
+                jnp.asarray(codes, jnp.int32),
+                jnp.asarray(arrays.feature),
+                jnp.asarray(arrays.threshold_bin),
+                jnp.asarray(arrays.left), jnp.asarray(arrays.right),
+                jnp.asarray(arrays.learning_rate * arrays.value,
+                            jnp.float32),
+                max_depth=arrays.max_depth, blk=blk, interpret=interpret)
+        with region("predict", "sync"):
+            return np.asarray(out, np.float64) + arrays.base
     if backend != "jax":
         raise ValueError(f"unknown tree-predict backend {backend!r}; "
                          "expected 'jax' or 'pallas'")
-    with x64():
+    x32 = np.asarray(x, np.float32)
+    with region("predict", "jax"), x64():
         fn = getattr(arrays, "_jitted", None)
         if fn is None:
             # learning_rate folded into the leaf values host-side, in

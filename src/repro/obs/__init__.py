@@ -13,7 +13,11 @@ oracle, both serving engines) accepts an ``obs=`` tracer and emits
     pool saturation, Page–Hinkley drift triggers, oracle refits,
     registry publishes;
   * **metrics** via :class:`MetricsRegistry` — counters, gauges, and
-    fixed-boundary histograms with a Prometheus text-exposition dump.
+    fixed-boundary histograms with a Prometheus text-exposition dump;
+  * **program spans on the profiler clock** via :func:`region` /
+    ``Tracer.region`` — ``repro:<layer>/<phase>`` annotations around the
+    decide, predict and serving hot paths, read against the device's
+    operations by :mod:`repro.obs.analyze.idle`.
 
 The hard contract is *zero perturbation*: the default
 :data:`NULL_TRACER` no-ops every hook, and a live :class:`Tracer` only
@@ -39,13 +43,13 @@ from repro.obs.chrome import export_chrome, validate_chrome
 from repro.obs.metrics import (LATENCY_BOUNDARIES, Counter, Gauge,
                                Histogram, MetricsRegistry)
 from repro.obs.trace import (NULL_TRACER, InstantEvent, NullTracer,
-                             SpanEvent, Tracer, postmortem_dump)
+                             SpanEvent, Tracer, postmortem_dump, region)
 
 __all__ = [
     "Tracer", "NullTracer", "NULL_TRACER", "SpanEvent", "InstantEvent",
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "LATENCY_BOUNDARIES", "export_chrome", "validate_chrome",
-    "postmortem_dump", "QuantileSketch",
+    "postmortem_dump", "region", "QuantileSketch",
 ]
 
 

@@ -15,9 +15,12 @@ this package reads what it recorded:
     quantiles; also a :class:`repro.obs.MetricsRegistry` kind via
     ``registry.quantile(name)`` (:mod:`~repro.obs.analyze.sketch`);
   * :func:`compare_rows` / the ``regress`` CLI — baseline regression
-    gating for CI (:mod:`~repro.obs.analyze.regress`).
+    gating for CI (:mod:`~repro.obs.analyze.regress`);
+  * :mod:`~repro.obs.analyze.idle` — a JAX profiler trace's device idle
+    time put down to the program's ``repro:`` spans and JAX's compile
+    events.
 
-CLI: ``python -m repro.obs.analyze {attribution,diff,regress} ...``.
+CLI: ``python -m repro.obs.analyze {attribution,diff,idle,regress} ...``.
 
 Import note: :mod:`repro.obs.metrics` lazily imports
 :class:`QuantileSketch` *inside* ``MetricsRegistry.quantile`` — keep

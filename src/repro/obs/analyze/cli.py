@@ -1,6 +1,6 @@
 """``python -m repro.obs.analyze`` — trace analytics from the shell.
 
-Three subcommands, mirroring the library entry points:
+Four subcommands, mirroring the library entry points:
 
 ``attribution TRACE [--json OUT] [--misses]``
     Phase attribution + deadline-miss report for one exported
@@ -8,6 +8,10 @@ Three subcommands, mirroring the library entry points:
 
 ``diff TRACE_A TRACE_B [--align task|arrival] [--top-k N] [--json OUT]``
     Differential profile of run B against baseline A.
+
+``idle TRACE_DIR [--window-event NAME] [--top N] [--json OUT]``
+    Device idle time of a JAX profiler trace put down to the program's
+    ``repro:`` spans and JAX's compile events.
 
 ``regress BASE [FRESH] [--tol T] [--tol-metric NAME=T ...]
 [--selftest] [--json OUT]``
@@ -59,6 +63,28 @@ def _cmd_diff(ns: argparse.Namespace) -> int:
     return 0
 
 
+def _cmd_idle(ns: argparse.Namespace) -> int:
+    from repro.obs.analyze.idle import attribute, load
+    rep = attribute(load(ns.trace_dir, window_event=ns.window_event),
+                    top=ns.top)
+    w = rep["window_s"]
+    if rep["devices"]:
+        print(f"window {w:.6g} s, {rep['devices']} device(s) busy "
+              f"{rep['busy_s']:.6g} s, idle {w - rep['busy_s']:.6g} s")
+    else:
+        print(f"window {w:.6g} s, no device operation in it")
+    print(f"  {'span':<24}{'count':>7}{'seconds':>11}{'idle s':>11}"
+          f"{'compile idle s':>16}{'compiles':>10}")
+    for name, p in rep["program"].items():
+        print(f"  {name:<24}{p['count']:>7}{p['seconds']:>11.4g}"
+              f"{p['idle_s']:>11.4g}{p['compile_idle_s']:>16.4g}"
+              f"{p['compiles']:>10}")
+    print("  longest idle gaps: " + ", ".join(
+        f"{n} {s * 1e3:.3g} ms" for n, s in rep["idle_gaps"]))
+    _dump(rep, ns.json)
+    return 0
+
+
 def _parse_tols(specs: Sequence[str]) -> dict:
     out = {}
     for spec in specs:
@@ -91,8 +117,8 @@ def _cmd_regress(ns: argparse.Namespace) -> int:
 def main(argv: Optional[Sequence[str]] = None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m repro.obs.analyze",
-        description="trace analytics: attribution, diff, regression "
-                    "gate")
+        description="trace analytics: attribution, diff, device idle, "
+                    "regression gate")
     sub = p.add_subparsers(dest="cmd", required=True)
 
     pa = sub.add_parser("attribution",
@@ -111,6 +137,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     pd.add_argument("--top-k", type=int, default=10)
     pd.add_argument("--json", default=None)
     pd.set_defaults(fn=_cmd_diff)
+
+    pi = sub.add_parser("idle", help="device idle by program span")
+    pi.add_argument("trace_dir", help="directory jax.profiler wrote")
+    pi.add_argument("--window-event", default=None,
+                    help="host event bounding the window (default: the "
+                         "extent of the program's spans)")
+    pi.add_argument("--top", type=int, default=10)
+    pi.add_argument("--json", default=None)
+    pi.set_defaults(fn=_cmd_idle)
 
     pr = sub.add_parser("regress",
                         help="regression gate (exit 1 on regression)")

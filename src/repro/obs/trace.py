@@ -12,6 +12,15 @@ A :class:`Tracer` collects two kinds of structured events:
     a point event — replan, split re-pick, pool saturation,
     Page–Hinkley drift trigger, oracle refit, registry publish.
 
+:meth:`~NullTracer.region` puts the program's hot layers on the
+profiler's clock: it always opens a ``jax.profiler.TraceAnnotation``
+named ``repro:<track>/<name>`` (free unless a profiler trace is being
+taken, where it lands on the same timeline as the device's operations
+and JAX's own compile events), and on a live :class:`Tracer` it also
+records a ``SpanEvent`` on the caller's clock.  Library code with no
+``obs=`` seam uses the module-level :func:`region` (the
+:data:`NULL_TRACER`'s).
+
 Timestamps are *whatever clock the caller lives on*: virtual seconds
 inside :mod:`repro.sim` (the engines pass event-loop / slab times —
 the tracer itself never reads a wall clock for them, keeping
@@ -47,7 +56,23 @@ from collections import deque
 from typing import Optional, Sequence
 
 __all__ = ["SpanEvent", "InstantEvent", "NullTracer", "Tracer",
-           "NULL_TRACER", "postmortem_dump"]
+           "NULL_TRACER", "postmortem_dump", "region"]
+
+#: prefix of the program's spans in a profiler trace
+PROFILER_PREFIX = "repro:"
+
+_TraceAnnotation = None
+
+
+def _annotation(track: str, name: str):
+    """The profiler's host event ``repro:<track>/<name>``.  jax is
+    imported on first use, so importing :mod:`repro.obs` does not pull
+    it in."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(f"{PROFILER_PREFIX}{track}/{name}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,6 +141,12 @@ class NullTracer:
                        args_cols=None) -> None:
         pass
 
+    def region(self, track: str, name: str, *, tid: int = 0,
+               args: Optional[dict] = None, now=None):
+        """Context manager around one phase of the program: the profiler
+        annotation ``repro:<track>/<name>`` (no span is recorded here)."""
+        return _annotation(track, name)
+
     def last(self, n: int = 64) -> list:
         return []
 
@@ -182,10 +213,12 @@ class Tracer(NullTracer):
 
             sojourn   [arrived, finished]
               queue_wait [arrived, started]          (omitted if 0)
-              service    [started, finished - transfer]
-              transfer   [finished - transfer, finished]  (omitted if 0)
+              service    [started, max(started, finished - transfer)]
+              transfer   [service end, finished]  (omitted if 0)
 
         The ``sojourn`` span carries ``args`` (split, deadline, ...).
+        The service end is clamped to ``started``: with a zero service
+        time, ``finished - transfer`` can round below ``started``.
         """
         arrived_s = float(arrived_s)
         started_s = float(started_s)
@@ -195,11 +228,24 @@ class Tracer(NullTracer):
                   args={"task": name, **(args or {})})
         if started_s > arrived_s:
             self.span(track, "queue_wait", arrived_s, started_s, tid=tid)
-        service_end = finished_s - transfer_s
+        service_end = max(finished_s - transfer_s, started_s)
         self.span(track, "service", started_s, service_end, tid=tid)
         if transfer_s > 0.0:
             self.span(track, "transfer", service_end, finished_s,
                       tid=tid)
+
+    def region(self, track: str, name: str, *, tid: int = 0,
+               args: Optional[dict] = None, now=None) -> "_Region":
+        """Context manager around one phase of the program: the profiler
+        annotation ``repro:<track>/<name>``, and a span on ``(track,
+        tid)`` stamped by ``now()`` (the caller's clock) on entry and
+        exit.  ``args`` is read when the region closes, so the caller
+        may add what it learnt inside (e.g. the split it chose)."""
+        if now is None:
+            raise ValueError(
+                f"region {track}/{name}: a live Tracer stamps spans on "
+                "the caller's clock; pass now=")
+        return _Region(self, track, name, tid, args, now)
 
     # -- ingestion: the fleet engine's slab path --------------------------
     def span_arrays(self, tracks: Sequence[str], tids, names,
@@ -311,6 +357,35 @@ class Tracer(NullTracer):
         matched B/E pairs with children nested inside parents."""
         from repro.obs.chrome import export_chrome
         return export_chrome(self, path)
+
+
+class _Region:
+    """:meth:`Tracer.region`'s context manager."""
+
+    __slots__ = ("tracer", "track", "name", "tid", "args", "now", "ann",
+                 "t0")
+
+    def __init__(self, tracer: Tracer, track: str, name: str, tid: int,
+                 args: Optional[dict], now):
+        self.tracer, self.track, self.name = tracer, track, name
+        self.tid, self.args, self.now = tid, args, now
+        self.ann = _annotation(track, name)
+
+    def __enter__(self) -> "_Region":
+        self.ann.__enter__()
+        self.t0 = self.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = self.now()
+        self.ann.__exit__(*exc)
+        self.tracer.span(self.track, self.name, self.t0, t1, tid=self.tid,
+                         args=self.args)
+
+
+#: :meth:`NullTracer.region` of :data:`NULL_TRACER`: the profiler
+#: annotation alone, for library code that has no ``obs=`` seam
+region = NULL_TRACER.region
 
 
 def postmortem_dump(tracer, *, clock_s: float, error: str = "",

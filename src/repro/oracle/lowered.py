@@ -39,6 +39,7 @@ from repro.core.predictors.linear import RidgeRegressor
 from repro.core.predictors.mlp import MLPRegressor
 from repro.kernels.tree_predict.ops import predict_trees
 from repro.kernels.tree_predict.ref import TreeArrays, flatten_gbt
+from repro.obs.trace import region
 
 
 class LoweredPredictor:
@@ -143,7 +144,9 @@ class LoweredTrees(LoweredPredictor):
         return cls((flatten_gbt(model),), False)
 
     def predict(self, x: np.ndarray, *, backend: str = "jax") -> np.ndarray:
-        cols = [predict_trees(x, a, backend=backend) for a in self.arrays]
+        with region("predict", "call"):
+            cols = [predict_trees(x, a, backend=backend)
+                    for a in self.arrays]
         if not self.multi_target:
             return cols[0]
         return np.stack(cols, axis=1)

@@ -61,6 +61,11 @@ class ContinuousBatchEngine:
     moment a slot happens to be free.  Inject ``clock=`` to share one
     virtual time axis with a :mod:`repro.sim` run; each admitted request
     records its admission instant on ``request.admitted_at``.
+
+    Its phases are :meth:`repro.obs.Tracer.region` spans on track
+    ``serve``: ``admit`` (holding ``plan``, ``prefill`` and ``splice``)
+    per admission and ``decode`` per step — profiler annotations always,
+    and spans on the engine clock when ``obs`` is a live tracer.
     """
 
     def __init__(self, cfg, *, slots: int = 4, max_len: int = 256,
@@ -155,27 +160,34 @@ class ContinuousBatchEngine:
         req.offload = decide_all(layers, envs, cost=self.cost,
                                  backend=self.decision_backend)[0]
         self.replans += 1
-        if self.obs.enabled:
-            self.obs.instant("continuous_engine", "replan",
-                             self.clock.now, tid=req.rid,
-                             args={"split": int(req.offload.split)})
 
     # -- admission ------------------------------------------------------------
+    def _now(self) -> float:
+        return self.clock.now
+
+    def _region(self, name: str, tid: int = 0, args=None):
+        """The engine's phase ``name`` on the profiler's clock, and on the
+        tracer's (the engine clock) when tracing."""
+        return self.obs.region("serve", name, tid=tid, args=args,
+                               now=self._now)
+
     def _admit(self, req: Request, slot: int):
         req.admitted_at = self.clock.now
-        if self.obs.enabled:
-            self.obs.instant("continuous_engine", "admit",
-                             self.clock.now, tid=req.rid,
-                             args={"slot": slot})
-        if self.cost is not None:
-            self._plan_offload(req)
-        batch = {"tokens": jnp.asarray(req.prompt[None], jnp.int32)}
-        logits, cache1 = self._prefill1(self.params, batch)
-        self._splice(slot, cache1)
+        with self._region("admit", req.rid, {"slot": slot}):
+            if self.cost is not None:
+                plan_args: dict = {}
+                with self._region("plan", req.rid, plan_args):
+                    self._plan_offload(req)
+                    plan_args["split"] = int(req.offload.split)
+            with self._region("prefill", req.rid):
+                batch = {"tokens": jnp.asarray(req.prompt[None], jnp.int32)}
+                logits, cache1 = self._prefill1(self.params, batch)
+                self.slot_last_tok[slot] = int(jnp.argmax(logits[0, -1]))
+            with self._region("splice", req.rid):
+                self._splice(slot, cache1)
         self.slot_pos[slot] = len(req.prompt)
         self.slot_req[slot] = req
         self.slot_remaining[slot] = req.max_new_tokens
-        self.slot_last_tok[slot] = int(jnp.argmax(logits[0, -1]))
         req.output = np.zeros(req.max_new_tokens, np.int32)
         req._written = 0              # type: ignore[attr-defined]
 
@@ -195,11 +207,13 @@ class ContinuousBatchEngine:
                         and queue[0].arrived_at <= self.clock.now:
                     self._admit(queue.pop(0), s)
             # one decode step for all active slots, ragged per-slot positions
-            toks = jnp.asarray(self.slot_last_tok[:, None], jnp.int32)
-            self.cache["pos"] = jnp.asarray(self.slot_pos, jnp.int32)
-            logits, self.cache = self._decode(self.params, {"token": toks},
-                                              self.cache)
-            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+            with self._region("decode"):
+                toks = jnp.asarray(self.slot_last_tok[:, None], jnp.int32)
+                self.cache["pos"] = jnp.asarray(self.slot_pos, jnp.int32)
+                logits, self.cache = self._decode(
+                    self.params, {"token": toks}, self.cache)
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                 np.int32)
             self.steps += 1
             self.clock.advance(self.step_latency_s)
             for s in range(self.slots):

@@ -172,6 +172,29 @@ def test_exported_lifecycles_well_formed(data):
     assert stats["n_instants"] == len(tracer.all_instants())
 
 
+@pytest.mark.parametrize("arrived,wait,service,transfer", [
+    (0.0, 14.792538958709294, 0.0, 50.0),     # hypothesis's find
+    (0.1, 0.2, 0.0, 0.7),
+    (1.0, 0.0, 2.0, 3.0),
+])
+def test_task_spans_service_never_ends_before_it_starts(arrived, wait,
+                                                        service, transfer):
+    """``finished - transfer`` can round below ``started`` when the
+    service time is 0: the service end is clamped to ``started`` and the
+    transfer starts there."""
+    tracer = Tracer()
+    started = arrived + wait
+    finished = started + service + transfer
+    tracer.task_spans("node@0", 0, "t0", arrived, started, finished,
+                      transfer_s=transfer)
+    spans = {s.name: s for s in tracer.all_spans()}
+    assert spans["service"].t0 == started
+    assert spans["service"].t1 >= started
+    assert spans["transfer"].t0 == spans["service"].t1
+    assert spans["transfer"].t1 == finished
+    validate_chrome(tracer.export_chrome(None))
+
+
 def test_slab_ingestion_matches_per_event_path():
     """span_arrays / instant_arrays are exactly n deferred task_spans /
     instant calls in column order."""
@@ -405,5 +428,9 @@ def test_serve_engines_emit_spans():
     done = ceng.serve(reqs)
     sojourns = [s for s in ctracer.all_spans() if s.name == "sojourn"]
     assert len(sojourns) == len(done)
-    assert {i.name for i in ctracer.all_instants()} >= {"admit"}
+    admits = [s for s in ctracer.all_spans()
+              if (s.track, s.name) == ("serve", "admit")]
+    assert sorted(s.tid for s in admits) == [r.rid for r in reqs]
+    assert all(s.args == {"slot": s.args["slot"]} for s in admits)
+    assert ctracer.all_instants() == []
     validate_chrome(ctracer.export_chrome(None))
