@@ -200,12 +200,15 @@ def phase_predict(gbt: GBTRegressor, n_rows: int, on_chip: bool) -> dict:
     np.testing.assert_allclose(f64, host, rtol=F64_RTOL, atol=1e-15)
     if on_chip:
         from repro.kernels.tree_predict.kernel import tree_predict_kernel
-        nodes = [jax.ShapeDtypeStruct(a.feature.shape, jnp.int32)] * 4
+        from repro.kernels.tree_predict.ops import level_layout
+        layout = level_layout(a)
         assert_compiled(
             tree_predict_kernel,
-            jax.ShapeDtypeStruct((n_rows, x.shape[1]), jnp.int32), *nodes,
-            jax.ShapeDtypeStruct(a.feature.shape, jnp.float32),
-            max_depth=a.max_depth)
+            jax.ShapeDtypeStruct((n_rows, x.shape[1]), jnp.int32),
+            *(jax.ShapeDtypeStruct(s.shape, s.dtype)
+              for s in (layout.node, layout.value)),
+            levels=layout.levels, n_trees=layout.n_trees,
+            thr_bits=layout.thr_bits, feat_bits=layout.feat_bits)
     return {"rows": n_rows, "trees": a.n_trees, "max_nodes": a.max_nodes,
             "pallas_atol": atol, "pallas_max_abs_err": float(err.max()),
             "rows_beyond_test_pin": int(np.sum(

@@ -12,8 +12,9 @@ every inference backend consumes:
     accelerated paths are tested against);
   * ``ops.predict_trees``        — the same descent as jitted XLA
     (sequential tree accumulation, so f64 results stay bit-for-bit);
-  * ``kernel.tree_predict_kernel`` — the fused Pallas TPU kernel (node
-    arrays resident in VMEM, one-hot gathers on the VPU).
+  * ``kernel.tree_predict_kernel`` — the fused Pallas TPU kernel (the
+    arrays re-laid level by level by ``ops.level_layout``, one-hot
+    gathers within the current level on the VPU).
 
 The same arrays are what predictor persistence
 (:mod:`repro.core.predictors.persist`) writes to ``.npz``, so a saved

@@ -67,15 +67,37 @@ def test_decide_split_kernel_compiles(one_chip, no_compile_cache):
     assert "tpu_custom_call" in text
 
 
-def test_tree_predict_kernel_compiles(one_chip, no_compile_cache):
-    """65536 rows, 100 trees of up to 256 nodes, depth 7."""
+#: per-level node counts of the compiled layouts: a full depth-7 tree
+#: (255 of 256 nodes), and the catalog cell's ensemble (100 trees of up to
+#: 1,427 nodes, depth 12: the widest level d over its trees)
+TREE_SHAPES = {
+    "depth7": (65536, 16, 100, (1, 2, 4, 8, 16, 32, 64, 128)),
+    "catalog": (262144, 7, 100, (1, 2, 4, 8, 16, 32, 62, 104, 154, 206,
+                                 258, 332, 386)),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(TREE_SHAPES))
+def test_tree_predict_kernel_compiles(one_chip, no_compile_cache, shape):
+    """The level-layout tree kernel at ``TREE_SHAPES[shape]``: rows,
+    features, trees, level widths."""
+    from repro.kernels.tree_predict.kernel import LANES, level_slots
+    n_rows, n_feat, n_trees, counts = TREE_SHAPES[shape]
+    slots = level_slots(counts)
+    levels = tuple((lo, w, d < len(counts) - 1, d > 0)
+                   for d, (lo, w) in enumerate(slots))
+    n_slots = slots[-1][0] + slots[-1][1]
+
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    fn = functools.partial(tree_predict_kernel, max_depth=7,
+    fn = functools.partial(tree_predict_kernel, levels=levels,
+                           n_trees=n_trees, thr_bits=6,
+                           feat_bits=(n_feat - 1).bit_length(),
                            interpret=False)
-    text = _hlo(fn, sds((65536, 16), jnp.int32),
-                *[sds((100, 256), jnp.int32)] * 4,
-                sds((100, 256), jnp.float32))
+    chunks = -(-n_trees // LANES)
+    text = _hlo(fn, sds((n_rows, n_feat), jnp.int32),
+                sds((chunks, n_slots, LANES), jnp.int32),
+                sds((chunks, n_slots, LANES), jnp.float32))
     assert "tpu_custom_call" in text
 
 
