@@ -11,12 +11,19 @@ runs in float32 at ``highest`` matmul precision, one layer at a time.
 The weights are laid out as the program's parameter tree (its
 checkpoint layout) and made here, in one jitted call, in the dtype they
 are served in.
+
+It also gives the serve driver what depends on the model's shape:
+``planner_layers`` (the model's layers as the admission planner prices
+them), ``prefill_flops`` and ``token_flops`` (the model FLOPs
+``serve.mfu`` counts) and ``small`` (its cut for the CPU tests).
 """
 from __future__ import annotations
 
 import functools
 
 import numpy as np
+
+import workcount
 
 
 def param_shapes(m: dict) -> dict:
@@ -192,3 +199,35 @@ def fp8_weights(w):
     s = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 448.0
     s = jnp.where(s > 0, s, 1.0)
     return (w / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+
+
+def planner_layers(m: dict, seq: int):
+    """Per-layer FLOPs and activation bytes of one sequence through the
+    decoder, as the admission planner states a model's layers: QKV and
+    output projections, attention scores, the MLP; activations are the
+    f16 hidden state."""
+    d, n = m["d_model"], m["num_layers"]
+    proj = 2.0 * seq * d * m["num_heads"] * m["head_dim"] * 2
+    kv = 2.0 * seq * d * m["num_kv_heads"] * m["head_dim"] * 2
+    scores = 2.0 * m["num_heads"] * seq * seq * m["head_dim"] * 2
+    n_mat = 2 if m["mlp_act"] in ("gelu_plain", "relu2") else 3
+    ff = n_mat * 2.0 * seq * d * m["d_ff"]
+    return (np.full(n, proj + kv + scores + ff), np.full(n, 2.0 * seq * d))
+
+
+def prefill_flops(m: dict, prompt_len: int) -> float:
+    """Model FLOPs of a prompt's prefill (dense GQA decoder)."""
+    return workcount.decoder_prefill_flops(m, prompt_len)
+
+
+def token_flops(m: dict, context: int, with_head: bool) -> float:
+    """Model FLOPs of one decoded token over ``context`` positions."""
+    return workcount.decoder_token_flops(m, context, with_head)
+
+
+def small(m: dict) -> dict:
+    """The model keys cut for the CPU tests: two layers of width 256, the
+    same family."""
+    return {"num_layers": 2, "d_model": 256, "num_heads": 4,
+            "num_kv_heads": 2, "head_dim": 64, "d_ff": 512,
+            "vocab_size": 512}

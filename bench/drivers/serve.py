@@ -13,6 +13,10 @@ requests take the exponential inter-arrival gaps, answer lengths and
 link bandwidths at evenly spaced quantiles, and the prompt lengths in
 exact proportion; the seed orders them and draws the prompt tokens.
 
+Whatever depends on the model's shape comes from the configuration's
+module (``configs/<config>.py``, the functions ``MODULE_FUNCTIONS``
+names), so a served model of any block enters by its own files.
+
 In the window the engine's clock is the wall clock: ``now`` is seconds
 since the window opened, ``advance`` (once per decode step) only counts,
 and ``advance_to`` sleeps until the next arrival.  Set-up's warm-up runs
@@ -78,11 +82,27 @@ class WallClock:
             time.sleep(wait)
 
 
+#: what a served configuration's module gives: its weights from the seed
+#: (``make_params``), the reference (``forward``, ``logits_at``), the
+#: control's weights (``fp8_weights``), the planner's statement of the
+#: model's layers (``planner_layers(m, seq) -> (flops[L], act[L])``), the
+#: model FLOPs of a prefill (``prefill_flops(m, prompt_len)``) and of a
+#: decoded token (``token_flops(m, context, with_head)``)
+MODULE_FUNCTIONS = ("make_params", "forward", "logits_at", "fp8_weights",
+                    "planner_layers", "prefill_flops", "token_flops")
+
+
 class State:
     pass
 
 
 def setup(ctx):
+    missing = [f for f in MODULE_FUNCTIONS
+               if not callable(getattr(ctx.cfg_mod, f, None))]
+    if missing:
+        raise AttributeError(f"{ctx.cell.cfg_path.name}: a served "
+                             f"configuration's module must define "
+                             f"{', '.join(missing)}")
     from repro.configs.base import ModelConfig
     from repro.core import costs as co
     from repro.hw import get_device
@@ -130,8 +150,7 @@ def warm_up(ctx, st, rng) -> None:
 
 def window(ctx, st, seconds: float, rate: float | None = None) -> dict:
     from repro.obs.trace import Tracer
-    from workcount import decoder_prefill_flops, decoder_token_flops
-    eng, m = st.engine, st.m
+    eng, m, mod = st.engine, st.m, ctx.cfg_mod
     gen = requests(ctx.traffic, seconds, st.r_req, m["vocab_size"], rate)
     reqs = [st.Request(rid=i, prompt=g["prompt"],
                        max_new_tokens=g["answer"], arrived_at=g["due"])
@@ -146,7 +165,7 @@ def window(ctx, st, seconds: float, rate: float | None = None) -> dict:
         k = counts["admitted"]
         counts["admitted"] += 1
         if ctx.trace.state == "tracing":
-            counts["traced_flops"] += decoder_prefill_flops(
+            counts["traced_flops"] += mod.prefill_flops(
                 m, gen[k]["prompt_len"])
         return gen[k]["link_bw"]
 
@@ -158,7 +177,7 @@ def window(ctx, st, seconds: float, rate: float | None = None) -> dict:
             counts["traced_steps"] += 1
             for s in range(eng.slots):
                 if eng.slot_req[s] is not None:
-                    counts["traced_flops"] += decoder_token_flops(
+                    counts["traced_flops"] += mod.token_flops(
                         m, int(eng.slot_pos[s]) + 1, True)
         if span[0] is not None:
             span[0].__exit__(None, None, None)
@@ -275,7 +294,7 @@ def plan_gaps(ctx, st, low=None, xp=np) -> tuple[float, float]:
     gap = err = 0.0
     for r in st.done:
         g = st.gen[r.rid]
-        flops, act = layer_costs(m, g["prompt_len"])
+        flops, act = ctx.cfg_mod.planner_layers(m, g["prompt_len"])
         t_dev, t_edge = pm.layer_times_ref(st.ens, pcfg, flops, act)
         env = (np.asarray([g["link_bw"]]), np.asarray([0.005]),
                np.asarray([4.0 * g["prompt_len"]]))
@@ -291,20 +310,6 @@ def plan_gaps(ctx, st, low=None, xp=np) -> tuple[float, float]:
         gap = max(gap, rel_gap(cost[split], best))
         err = max(err, rel_gap(abs(total - best) + best, best))
     return gap, err
-
-
-def layer_costs(m: dict, seq: int):
-    """Per-layer FLOPs and activation bytes of one sequence through the
-    decoder, as the admission planner states a model's layers: QKV and
-    output projections, attention scores, the MLP; activations are the
-    f16 hidden state."""
-    d, n = m["d_model"], m["num_layers"]
-    proj = 2.0 * seq * d * m["num_heads"] * m["head_dim"] * 2
-    kv = 2.0 * seq * d * m["num_kv_heads"] * m["head_dim"] * 2
-    scores = 2.0 * m["num_heads"] * seq * seq * m["head_dim"] * 2
-    n_mat = 2 if m["mlp_act"] in ("gelu_plain", "relu2") else 3
-    ff = n_mat * 2.0 * seq * d * m["d_ff"]
-    return (np.full(n, proj + kv + scores + ff), np.full(n, 2.0 * seq * d))
 
 
 def readings(ctx, st) -> dict:

@@ -4,11 +4,13 @@
     python bench/faults.py --workload edge-metro.sweep --seeds 3 --seconds 4
 
 Each fault wraps the one function of the program where an answer is
-produced (a kernel, the fleet scan, the model's decode step) and breaks what it returns.
-The tests under ``tests/`` plant them on the CPU at a cut size; this
-script plants each in turn on the chip at the cell's own size and prints,
-for every seed and fault, whether the run read ``correct`` and the
-numbers it compared.  ``none`` is the sound run beside them.
+produced (a kernel, the fleet scan, the model's decode step) and breaks
+what it returns.  Faults are kept by driver, so every cell of a driver
+gets its faults; a cell's driver is found through ``BENCHMARK.json`` and
+its traffic file.  The tests under ``tests/`` plant them on the CPU at a
+cut size; this script plants each in turn on the chip at the cell's own
+size and prints, for every seed and fault, whether the run read
+``correct`` and the numbers it compared.  ``none`` is the sound run beside them.
 """
 from __future__ import annotations
 
@@ -16,6 +18,8 @@ import argparse
 import importlib
 import json
 import sys
+
+from common import BENCH, ROOT, load_json
 
 DECIDE = ("repro.kernels.decide_split.kernel", "decide_split_kernel")
 TREE = ("repro.kernels.tree_predict.kernel", "tree_predict_kernel")
@@ -75,17 +79,17 @@ SPLIT_HALF = _splits(lambda s, c, n: (_half(s, 0), _half(c, 0.0)))
 SPLIT_CONSTANT = _splits(lambda s, c, n: (s * 0, c * 0.0))
 
 FAULTS = {
-    "edge-metro.sweep": {
+    "sweep": {
         "altered": (DECIDE, SPLIT_ALTERED),
         "half": (DECIDE, SPLIT_HALF),
         "constant": (DECIDE, SPLIT_CONSTANT),
     },
-    "edge-metro.catalog": {
+    "catalog": {
         # every 7th row's sum moved on
         "altered": (TREE, _rows(lambda out: out.at[::7].add(0.05))),
         "half": (TREE, _rows(lambda out: _half(out, 0.0))),
     },
-    "edge-metro.day": {
+    "day": {
         # every task's node moved on by one
         "altered": (SCAN, _placed(lambda j, s, f, e, n: ((j + 1) % n, s, f,
                                                           e))),
@@ -94,7 +98,7 @@ FAULTS = {
             _np_half(j, 0), _np_half(s, 0.0), _np_half(f, 0.0),
             _np_half(e, 0.0)))),
     },
-    "minitron-4b.chat": {
+    "serve": {
         # one token id always wins
         "token": (DECODE, _step(lambda lg, new, old: (lg.at[..., 7].add(1e3),
                                                       new))),
@@ -106,10 +110,19 @@ FAULTS = {
 }
 
 
-def plant(cell: str, name: str, setattr_=setattr) -> None:
+def faults_of(cell: str, spec: dict | None = None) -> dict:
+    """The faults of ``cell``'s driver (the ``driver`` of the traffic
+    file its entry in ``spec``, by default ``BENCHMARK.json``, names)."""
+    spec = spec or load_json(ROOT / "BENCHMARK.json")
+    traffic = {w["name"]: w["traffic"] for w in spec["workloads"]}[cell]
+    return FAULTS[load_json(BENCH / "traffic" / f"{traffic}.json")["driver"]]
+
+
+def plant(cell: str, name: str, setattr_=setattr,
+          spec: dict | None = None) -> None:
     """Put fault ``name`` of ``cell`` in place, through ``setattr_`` (a
     test's ``monkeypatch.setattr`` undoes it at the test's end)."""
-    (mod_name, attr), wrap = FAULTS[cell][name]
+    (mod_name, attr), wrap = faults_of(cell, spec)[name]
     mod = importlib.import_module(mod_name)
     setattr_(mod, attr, wrap(getattr(mod, attr)))
 
@@ -122,7 +135,6 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=4.0)
     args = ap.parse_args(argv)
     import run
-    from common import load_json
     sys.path.insert(0, str(run.ROOT / "src"))
     import jax
     devices = jax.devices()
@@ -132,7 +144,7 @@ def main(argv=None) -> int:
     from peaks import peaks
     cache = run.use_compile_cache()
     spec = load_json(run.ROOT / "BENCHMARK.json")
-    names = sorted(FAULTS[args.workload])
+    names = sorted(faults_of(args.workload, spec))
     for k in range(args.seeds):
         seed = args.base + 1000 * k
         for name in ["none", *names]:
@@ -141,7 +153,7 @@ def main(argv=None) -> int:
             if name != "none":
                 plant(args.workload, name,
                       lambda m, a, v: (undo.append((m, a, getattr(m, a))),
-                                       setattr(m, a, v)))
+                                       setattr(m, a, v)), spec)
             try:
                 out = run.run_cell(cell, seed, args.seconds, False,
                                    devices[:cell.entry["chips"]],

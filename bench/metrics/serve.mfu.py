@@ -1,7 +1,8 @@
 """Model FLOPs of every prompt and generated token the engine processed
-in the traced window (``workcount.decoder_*``: 2 per matmul parameter
-plus causal attention, the head where logits are needed) over the
-window's wall time at the chip's peak."""
+in the traced window (the configuration module's ``prefill_flops`` and
+``token_flops``; for a dense decoder ``workcount.decoder_*``: 2 per
+matmul parameter plus causal attention, the head where logits are
+needed) over the window's wall time at the chip's peak."""
 
 
 def read(record):

@@ -22,8 +22,9 @@ SEED = 2**31 + 977
 def small(name: str, spec: dict | None = None) -> run.Cell:
     """The named cell with its sizes cut: 4096 users on links ten times
     slower (the ensemble at its full shape), 4096-row batches through an
-    8-tree ensemble of depth 6, days of 1400 tasks on 16 nodes, a
-    two-layer model of the same family.
+    8-tree ensemble of depth 6, days of 1400 tasks on 16 nodes, a served
+    model cut to the keys its configuration module's ``small(m)`` returns,
+    on 4 slots.
 
     The full sweep's 2^20 users hold thousands a call whose best split
     is not 0; at the full cell's links 4096 users hold a handful, in one
@@ -42,9 +43,7 @@ def small(name: str, spec: dict | None = None) -> run.Cell:
         cfg["fleet"]["nodes"] = 16
         tr.update(days=2, diurnal_tasks=700, burst_tasks=700, check_days=1)
     if tr["driver"] == "serve":
-        cfg["model"].update(num_layers=2, d_model=256, num_heads=4,
-                            num_kv_heads=2, head_dim=64, d_ff=512,
-                            vocab_size=512)
+        cfg["model"].update(cell.config_module().small(cfg["model"]))
         cfg["serving"].update(slots=4, max_len=80)
         tr.update(prompt_lens=[8, 16, 32, 64], answer_median=6,
                   answer_min=2, answer_max=15, rate_per_s=4.0,

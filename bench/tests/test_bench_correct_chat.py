@@ -36,7 +36,7 @@ def test_control_separates_from_sound_runs():
         shutil.rmtree(ctx.tmpdir, ignore_errors=True)
 
 
-MODEL_FAULTS = sorted(set(faults.FAULTS[CELL]) - {"planner"})
+MODEL_FAULTS = sorted(set(faults.faults_of(CELL)) - {"planner"})
 
 
 @pytest.mark.parametrize("fault", MODEL_FAULTS)

@@ -12,28 +12,34 @@ import small_cells  # first: puts bench/ on the path
 import faults  # noqa: E402
 
 CELL = "edge-metro.day"
-# the cell's entries, kept out of BENCHMARK.json until its bound is
-# measured on the chip
+# the cell's entries, kept out of BENCHMARK.json until its traffic is
+# sized from a stated source
 ENTRIES = {
     "workloads": [{"name": CELL, "config": "edge-metro", "traffic": "day",
                    "chips": 1, "why": "what-if fleet days"}],
     "end_to_end": [{"name": "sim_tasks_per_s", "unit": "tasks/s",
                     "better": "higher", "bound": 0.05,
                     "source": "host_clock", "workloads": [CELL]}],
-    "per_layer": [{"name": n, "unit": "%", "better": b,
-                   "source": "host_clock", "layer": "fleet engine",
-                   "moves": "sim_tasks_per_s", "workloads": [CELL]}
-                  for n, b in (("fleet.mfu", "higher"),
-                               ("device_idle.fleet", "lower"))],
+    "per_layer": [
+        {"name": "fleet.mfu", "unit": "%", "better": "higher",
+         "source": "host_clock", "layer": "fleet engine",
+         "moves": "sim_tasks_per_s", "workloads": [CELL]},
+        {"name": "device_idle.fleet", "unit": "%", "better": "lower",
+         "source": "device_trace", "layer": "device",
+         "moves": "sim_tasks_per_s", "workloads": [CELL]}],
 }
 
 
-def small_day():
+def day_spec() -> dict:
     from common import load_json
     spec = load_json(small_cells.ROOT / "BENCHMARK.json")
     for key, extra in ENTRIES.items():
         spec[key] = spec[key] + extra
-    return small_cells.small(CELL, spec)
+    return spec
+
+
+def small_day():
+    return small_cells.small(CELL, day_spec())
 
 
 def test_sound_run_is_correct():
@@ -53,9 +59,10 @@ def test_control_fails_a_limit():
         shutil.rmtree(ctx.tmpdir, ignore_errors=True)
 
 
-@pytest.mark.parametrize("fault", sorted(faults.FAULTS[CELL]))
+@pytest.mark.parametrize("fault", sorted(faults.faults_of(CELL,
+                                                           day_spec())))
 def test_broken_scan_reads_false(monkeypatch, fault):
-    faults.plant(CELL, fault, monkeypatch.setattr)
+    faults.plant(CELL, fault, monkeypatch.setattr, day_spec())
     out = small_cells.run_small(small_day())
     assert not out["correct"], out["checks"]
 

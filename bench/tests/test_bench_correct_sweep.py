@@ -30,7 +30,7 @@ def test_control_fails_a_limit():
         shutil.rmtree(ctx.tmpdir, ignore_errors=True)
 
 
-@pytest.mark.parametrize("fault", sorted(faults.FAULTS[CELL]))
+@pytest.mark.parametrize("fault", sorted(faults.faults_of(CELL)))
 def test_broken_kernel_reads_false(monkeypatch, fault):
     faults.plant(CELL, fault, monkeypatch.setattr)
     out = small_cells.run_small(small_cells.small(CELL))
