@@ -26,6 +26,7 @@ class ModelConfig:
 
     # --- MLP / norm flavour ---
     mlp_act: str = "silu"           # silu->SwiGLU, gelu->GeGLU, gelu_plain->MLP
+    embed_scale: bool = True        # token embedding × sqrt(d_model)
     use_qk_norm: bool = False
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
@@ -34,6 +35,13 @@ class ModelConfig:
     attn_kind: str = "gqa"          # gqa | mla | none
     use_rope: bool = True
     rope_theta: float = 10000.0
+    # YaRN rope scaling (DeepSeek-V2's ``rope_scaling``); factor 0 = off
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     window: int = 0                 # 0 = full causal; >0 = sliding window
 
     # --- MLA (DeepSeek-V2) ---
@@ -44,9 +52,12 @@ class ModelConfig:
     mla_absorbed: bool = False      # decode-path weight absorption (§Perf)
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0            # the router's outputs
+    experts_held: int = 0           # experts 0..n-1 held here; 0 = all
+    first_dense_layers: int = 0     # leading layers with a d_ff MLP
     num_shared_experts: int = 0
     top_k: int = 0
+    norm_topk_prob: bool = True     # renormalise the top-k weights
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001
@@ -87,6 +98,10 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return max(self.num_heads // max(self.num_kv_heads, 1), 1)
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def d_inner(self) -> int:

@@ -295,22 +295,44 @@ def workload_layer_costs(wc, batch_size: Optional[int] = None
 def transformer_layer_costs(cfg, seq_len: int, batch_size: int
                             ) -> list[LayerCost]:
     """Analytic per-layer inference costs of an assigned architecture —
-    the pod-scale analogue used by the placement simulator."""
+    the pod-scale analogue used by the placement simulator.
+
+    GQA prices the query/output and key/value projections and the scores
+    and values at ``head_dim``.  MLA prices the query, the latent
+    down-projection and the rope key, the latent's up-projections to
+    keys and values, the output projection, and scores at ``nope + rope``
+    and values at ``v_head_dim``.  A leading dense layer prices its
+    ``d_ff`` MLP; an MoE layer the router and the experts a token
+    activates (``top_k`` routed plus the shared ones) of the whole model,
+    whatever share of them one chip holds."""
     d, l = cfg.d_model, max(cfg.num_layers, 1)
     t = seq_len * batch_size
-    attn_proj = 2.0 * t * d * (cfg.num_heads * cfg.head_dim) * 2
-    attn_kv = 2.0 * t * d * (cfg.num_kv_heads * cfg.head_dim) * 2
-    attn_scores = 2.0 * batch_size * cfg.num_heads * seq_len * seq_len \
-        * cfg.head_dim * 2
-    if cfg.num_experts:
-        ff = 3 * 2.0 * t * d * cfg.moe_d_ff * (cfg.top_k
-                                               + cfg.num_shared_experts)
-    elif cfg.d_ff:
-        n_mat = 2 if cfg.mlp_act in ("gelu_plain", "relu2") else 3
-        ff = n_mat * 2.0 * t * d * cfg.d_ff
+    if cfg.attn_kind == "mla":
+        h, dn, dr, dv, r = (cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                            cfg.v_head_dim, cfg.kv_lora_rank)
+        attn = (2.0 * t * d * (h * (dn + dr) + r + dr)
+                + 2.0 * t * r * h * (dn + dv) + 2.0 * t * h * dv * d
+                + 2.0 * batch_size * h * seq_len * seq_len * (dn + dr + dv))
     else:
-        ff = 2.0 * t * d * d * 4     # xlstm-style block projections
-    per_layer = attn_proj + attn_kv + attn_scores + ff
+        attn_proj = 2.0 * t * d * (cfg.num_heads * cfg.head_dim) * 2
+        attn_kv = 2.0 * t * d * (cfg.num_kv_heads * cfg.head_dim) * 2
+        attn_scores = 2.0 * batch_size * cfg.num_heads * seq_len * seq_len \
+            * cfg.head_dim * 2
+        attn = attn_proj + attn_kv + attn_scores
+    if cfg.d_ff:
+        n_mat = 2 if cfg.mlp_act in ("gelu_plain", "relu2") else 3
+        dense_ff = n_mat * 2.0 * t * d * cfg.d_ff
+    else:
+        dense_ff = 2.0 * t * d * d * 4     # xlstm-style block projections
+    if cfg.num_experts:
+        moe_ff = (3 * 2.0 * t * d * cfg.moe_d_ff
+                  * (cfg.top_k + cfg.num_shared_experts)
+                  + 2.0 * t * d * cfg.num_experts)
+    else:
+        moe_ff = dense_ff
     act = 2.0 * t * d
-    return [LayerCost(f"layer{i}", flops=per_layer, act_bytes=act)
+    return [LayerCost(f"layer{i}",
+                      flops=attn + (dense_ff if i < cfg.first_dense_layers
+                                    else moe_ff),
+                      act_bytes=act)
             for i in range(l)]

@@ -54,13 +54,14 @@ def _leaf_name(path) -> str:
 
 
 def _num_stack_dims(path) -> int:
-    """Layer-stacked leaves live under 'layers'/'mamba'/'mlstm'/'slstm'/
-    'enc_layers'/'dec_layers'; their leading dims are stack axes."""
+    """Layer-stacked leaves live under 'layers'/'dense_layers'/'mamba'/
+    'mlstm'/'slstm'/'enc_layers'/'dec_layers'; their leading dims are stack
+    axes."""
     parts = [str(getattr(p, "key", "")) for p in path]
     if "mamba" in parts or "mlstm" in parts:
         return 2                      # [G, K, ...]
-    if any(s in parts for s in ("layers", "enc_layers", "dec_layers",
-                                "slstm")):
+    if any(s in parts for s in ("layers", "dense_layers", "enc_layers",
+                                "dec_layers", "slstm")):
         return 1
     return 0
 
@@ -91,7 +92,7 @@ def partition_spec_for(path, shape: tuple[int, ...], cfg, *,
         tp_ok = try_tp(1, body[1])                   # vocab cols
     elif name in ("w_gate", "w_up", "w_down") and len(body) == 3:
         # MoE expert weights [E, d, ff]: expert parallelism
-        if tp > 1 and cfg.num_experts and cfg.num_experts % tp == 0:
+        if tp > 1 and body[0] % tp == 0:
             spec[nstack] = "model"
             tp_ok = True
             if report:

@@ -7,6 +7,8 @@ Numerics policy (TPU-native):
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -58,21 +60,75 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor ``0.1 · mscale · ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, cfg) -> jax.Array:
+    """YaRN inverse frequencies (DeepSeek-V2): below the correction range
+    (where a dim turns ``yarn_beta_fast`` to ``yarn_beta_slow`` times over
+    ``yarn_original_max_pos`` positions) the plain frequency, above it the
+    frequency over ``yarn_factor``, a linear ramp between."""
+    theta = cfg.rope_theta
+
+    def dim_at(rotations):
+        return (head_dim * math.log(cfg.yarn_original_max_pos
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_at(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_at(cfg.yarn_beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    expo = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    extra = 1.0 / theta ** expo
+    inter = 1.0 / (cfg.yarn_factor * theta ** expo)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def yarn_softmax_scale(cfg) -> float:
+    """The factor YaRN puts on the attention softmax scale,
+    ``yarn_mscale(factor, mscale_all_dim)²``; 1 without YaRN."""
+    if cfg.yarn_factor <= 1:
+        return 1.0
+    return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, *,
+               inv_freq: jax.Array | None = None,
+               scale: float = 1.0) -> jax.Array:
     """Rotate ``x`` ([..., S, H, D]) by ``positions`` ([..., S]).
 
     Uses the split-halves convention (first half paired with second half),
-    matching the LLaMA/Gemma family.
+    matching the LLaMA/Gemma family.  ``inv_freq`` replaces the plain
+    frequencies and ``scale`` multiplies cos and sin (YaRN).
     """
     head_dim = x.shape[-1]
-    inv = rope_freqs(head_dim, theta)                       # [D/2]
+    inv = rope_freqs(head_dim, theta) if inv_freq is None else inv_freq
     ang = positions[..., None].astype(jnp.float32) * inv    # [..., S, D/2]
     cos = jnp.cos(ang)[..., None, :]                        # [..., S, 1, D/2]
     sin = jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_for(x: jax.Array, positions: jax.Array, cfg) -> jax.Array:
+    """``apply_rope`` with the configuration's scaling (YaRN where
+    ``yarn_factor`` > 1, else plain)."""
+    if cfg.yarn_factor <= 1:
+        return apply_rope(x, positions, cfg.rope_theta)
+    f = cfg.yarn_factor
+    return apply_rope(x, positions, cfg.rope_theta,
+                      inv_freq=yarn_freqs(x.shape[-1], cfg),
+                      scale=yarn_mscale(f, cfg.yarn_mscale)
+                      / yarn_mscale(f, cfg.yarn_mscale_all_dim))
 
 
 def sinusoidal_positions(seq: int, d_model: int) -> jax.Array:

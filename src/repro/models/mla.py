@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, matmul, rms_norm
+from repro.models.layers import matmul, rms_norm, rope_for, yarn_softmax_scale
 
 NEG_INF = -1e30
 
@@ -42,7 +42,7 @@ def _queries(params, x, cfg, positions):
     h, dn, dr = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     q = matmul(x, params["wq"]).reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope_for(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -50,8 +50,14 @@ def _latent(params, x, cfg, positions):
     c_kv = rms_norm(matmul(x, params["w_dkv"]), params["kv_norm_scale"],
                     cfg.norm_eps)                       # [B,S,r]
     k_rope = matmul(x, params["w_kr"])[:, :, None, :]   # [B,S,1,dr]
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)[:, :, 0]
+    k_rope = rope_for(k_rope, positions, cfg)[:, :, 0]
     return c_kv, k_rope
+
+
+def softmax_scale(cfg) -> float:
+    """``(dn + dr) ** -0.5``, times YaRN's ``mscale²`` where it scales."""
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 \
+        * yarn_softmax_scale(cfg)
 
 
 def mla_self_attention(params, x, cfg, *, positions, impl="chunked"):
@@ -69,7 +75,7 @@ def mla_self_attention(params, x, cfg, *, positions, impl="chunked"):
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))],
         axis=-1)
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
     fn = naive_attention if impl == "naive" else chunked_attention
     out = fn(q, k, v, causal=True, scale=scale)
     out = matmul(out.reshape(b, s, h * dv), params["wo"])
@@ -80,19 +86,25 @@ def mla_decode_attention(params, x, cfg, *, ckv_cache, kr_cache, pos,
                          absorbed=True):
     """One-token MLA against the latent cache.
 
-    ckv_cache [B,Smax,r]; kr_cache [B,Smax,dr]; returns (out, new caches).
+    ckv_cache [B,Smax,r]; kr_cache [B,Smax,dr]; ``pos`` is the incoming
+    token's position, scalar or [B] for ragged continuous-batching slots;
+    returns (out, new caches).
     """
     b, _, _ = x.shape
-    h, dn, dv, dr, r = (cfg.num_heads, cfg.qk_nope_dim, cfg.v_head_dim,
-                        cfg.qk_rope_dim, cfg.kv_lora_rank)
-    positions = jnp.full((b, 1), pos, dtype=jnp.int32)
+    h, dn, dv, r = (cfg.num_heads, cfg.qk_nope_dim, cfg.v_head_dim,
+                    cfg.kv_lora_rank)
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    positions = pos[:, None]
     q_nope, q_rope = _queries(params, x, cfg, positions)    # [B,1,h,dn/dr]
     c_new, kr_new = _latent(params, x, cfg, positions)
-    ckv_cache = jax.lax.dynamic_update_slice_in_dim(ckv_cache, c_new, pos, 1)
-    kr_cache = jax.lax.dynamic_update_slice_in_dim(kr_cache, kr_new, pos, 1)
+    # per-row write positions
+    upd = jax.vmap(lambda c, new, p: jax.lax.dynamic_update_slice_in_dim(
+        c, new, p, axis=0))
+    ckv_cache = upd(ckv_cache, c_new, pos)
+    kr_cache = upd(kr_cache, kr_new, pos)
     smax = ckv_cache.shape[1]
-    valid = jnp.arange(smax) <= pos
-    scale = (dn + dr) ** -0.5
+    valid = jnp.arange(smax)[None, :] <= pos[:, None]        # [B,Smax]
+    scale = softmax_scale(cfg)
 
     dt = x.dtype
     if absorbed:
@@ -102,7 +114,7 @@ def mla_decode_attention(params, x, cfg, *, ckv_cache, kr_cache, pos,
         s_nope = jnp.einsum("bhr,bsr->bhs", q_lat, ckv_cache.astype(dt))
         s_rope = jnp.einsum("bohd,bsd->bhs", q_rope, kr_cache.astype(dt))
         s = (s_nope + s_rope).astype(jnp.float32) * scale
-        s = s + jnp.where(valid, 0.0, NEG_INF)[None, None, :]
+        s = s + jnp.where(valid, 0.0, NEG_INF)[:, None, :]
         p = jax.nn.softmax(s, axis=-1).astype(dt)
         o_lat = jnp.einsum("bhs,bsr->bhr", p, ckv_cache.astype(dt))
         w_uv = params["w_uv"].reshape(r, h, dv)
@@ -115,7 +127,7 @@ def mla_decode_attention(params, x, cfg, *, ckv_cache, kr_cache, pos,
         s_nope = jnp.einsum("bohd,bshd->bhs", q_nope, k_nope.astype(dt))
         s_rope = jnp.einsum("bohd,bsd->bhs", q_rope, kr_cache.astype(dt))
         sc = (s_nope + s_rope).astype(jnp.float32) * scale
-        sc = sc + jnp.where(valid, 0.0, NEG_INF)[None, None, :]
+        sc = sc + jnp.where(valid, 0.0, NEG_INF)[:, None, :]
         p = jax.nn.softmax(sc, axis=-1).astype(dt)
         out = jnp.einsum("bhs,bshd->bhd", p, v)
         out = out.reshape(b, 1, h * dv).astype(x.dtype)

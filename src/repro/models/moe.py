@@ -8,12 +8,18 @@ clean when the expert buffer is sharded over the ``model`` mesh axis
   1. route: softmax(router) → top-k experts/weights per token
   2. argsort token-choices by expert id → contiguous per-expert runs
   3. expert buffer [E, C, d] gathered from the sorted tokens (overflow beyond
-     capacity C is dropped, matching Switch/GShard semantics)
+     capacity C is dropped, matching Switch/GShard semantics; serving passes
+     ``dropless=True``, C = all tokens, so nothing is dropped)
   4. batched expert matmuls [E,C,d]×[E,d,ff]
   5. inverse-permutation gather back to [T, k, d] → weighted combine
 
 The auxiliary load-balance loss (DeepSeek eq. 12-style) is returned for the
 training objective.
+
+A layer may hold a share of the experts (``cfg.experts_held``, experts
+``0 .. held-1``, as one chip of an expert-parallel deployment holds them):
+the router keeps all ``num_experts`` outputs and its top-k, and only the
+(token, choice) pairs routed to a held expert contribute.
 """
 from __future__ import annotations
 
@@ -24,9 +30,9 @@ from repro.models.layers import _ACTS, gated_mlp, matmul, mlp_param_shapes
 
 
 def moe_param_shapes(cfg) -> dict:
-    d, e, ff = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    d, e, ff = cfg.d_model, cfg.n_experts_held, cfg.moe_d_ff
     shapes = {
-        "router": (d, e),
+        "router": (d, cfg.num_experts),
         "w_gate": (e, d, ff),
         "w_up": (e, d, ff),
         "w_down": (e, ff, d),
@@ -49,8 +55,9 @@ def route(params, x_flat: jax.Array, cfg):
                         params["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)                  # [T,E]
     weights, idx = jax.lax.top_k(probs, cfg.top_k)           # [T,k]
-    weights = weights / jnp.maximum(
-        weights.sum(axis=-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.maximum(
+            weights.sum(axis=-1, keepdims=True), 1e-9)
     # load-balance auxiliary loss: E * sum_e f_e * P_e
     e = cfg.num_experts
     f = jnp.zeros((e,), jnp.float32).at[idx.reshape(-1)].add(
@@ -116,8 +123,14 @@ def _expert_compute(x_flat, idx, weights, w_gate, w_up, w_down, cfg,
     return y.astype(x_flat.dtype)
 
 
-def moe_mlp(params, x: jax.Array, cfg):
+def moe_mlp(params, x: jax.Array, cfg, *, dropless: bool = False,
+            count_held: bool = False):
     """x [B,S,d] (or [T,d]) → (y same shape, aux_loss).
+
+    ``dropless`` sizes each expert's buffer for every local token, so no
+    routed pair is dropped (serving); otherwise ``capacity``.
+    ``count_held`` appends the number of (token, choice) pairs routed to
+    a held expert: (y, aux_loss, pairs).
 
     On the production mesh this runs under ``shard_map``: tokens stay in
     their data shard, each "model" shard computes only its own experts over
@@ -130,7 +143,7 @@ def moe_mlp(params, x: jax.Array, cfg):
     d = orig_shape[-1]
     x_flat = x.reshape(-1, d)
     t = x_flat.shape[0]
-    e = cfg.num_experts
+    e = cfg.n_experts_held
 
     weights, idx, aux = route(params, x_flat, cfg)
 
@@ -148,7 +161,7 @@ def moe_mlp(params, x: jax.Array, cfg):
         tok_dp = dp if (t % max(dp_size, 1) == 0 and dp_size > 1) else ()
         t_local = t // dp_size if tok_dp else t
         n_local = e // tp_size
-        cap = capacity(t_local, cfg)
+        cap = t_local if dropless else capacity(t_local, cfg)
         tok_spec = P(tok_dp if tok_dp else None)
         w_spec = P("model", None, None)
 
@@ -168,11 +181,14 @@ def moe_mlp(params, x: jax.Array, cfg):
         )(x_flat, idx, weights, params["w_gate"], params["w_up"],
           params["w_down"])
     else:
-        cap = capacity(t, cfg)
+        cap = t if dropless else capacity(t, cfg)
         y = _expert_compute(x_flat, idx, weights, params["w_gate"],
                             params["w_up"], params["w_down"], cfg, 0, e, cap)
 
     out = y.astype(x.dtype)
     if cfg.num_shared_experts:
         out = out + gated_mlp(x_flat, params["shared"], cfg.mlp_act)
+    if count_held:
+        return (out.reshape(orig_shape), aux,
+                jnp.sum(idx < e, dtype=jnp.int32))
     return out.reshape(orig_shape), aux

@@ -37,7 +37,8 @@ def build_model(cfg, *, impl: str = "chunked") -> ModelAPI:
             init_params=lambda key: mod.init_params(cfg, key),
             train_loss=lambda p, b: mod.train_loss(p, b, cfg, impl=impl),
             prefill=lambda p, b, m: mod.prefill(p, b, cfg, m, impl=impl),
-            decode_step=lambda p, b, c: mod.decode_step(p, b, c, cfg),
+            decode_step=lambda p, b, c, **kw: mod.decode_step(p, b, c, cfg,
+                                                              **kw),
             init_cache=lambda bs, m: mod.init_cache(cfg, bs, m),
             cache_shapes=lambda bs, m: mod.cache_shapes(cfg, bs, m),
             param_shapes=lambda: mod.param_shapes(cfg),

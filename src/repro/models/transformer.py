@@ -3,7 +3,10 @@
 Layers are *stacked* (leading L axis on every layer leaf) and executed with
 ``jax.lax.scan`` so the HLO contains one layer body regardless of depth —
 essential for tractable multi-pod compile times.  Training wraps the layer
-body in ``jax.checkpoint`` (remat).
+body in ``jax.checkpoint`` (remat).  An MoE model's leading dense layers
+(``cfg.first_dense_layers``, a ``d_ff`` MLP in place of the experts) are a
+stack of their own, ``dense_layers`` in the parameters and the cache, run
+before ``layers``.
 
 Uniform model API (shared by all families via ``repro.models.registry``):
 
@@ -35,30 +38,38 @@ PyTree = Any
 # --------------------------------------------------------------------------
 # Parameter shapes
 # --------------------------------------------------------------------------
-def layer_shapes(cfg) -> dict:
+def layer_shapes(cfg, dense: bool = False) -> dict:
+    """One layer's shapes; ``dense`` gives a leading dense layer's."""
     d = cfg.d_model
     shapes = {"ln1_scale": (d,), "ln2_scale": (d,)}
     if cfg.attn_kind == "mla":
         shapes["attn"] = mla_mod.mla_param_shapes(cfg)
     else:
         shapes["attn"] = attn_mod.attn_param_shapes(cfg)
-    if cfg.num_experts:
+    if cfg.num_experts and not dense:
         shapes["moe"] = moe_mod.moe_param_shapes(cfg)
     else:
         shapes["mlp"] = mlp_param_shapes(d, cfg.d_ff, cfg.mlp_act)
     return shapes
 
 
+def stacks(cfg) -> tuple[tuple[str, int], ...]:
+    """The layer stacks in order: (name, layer count)."""
+    n_dense = cfg.first_dense_layers
+    out = (("layers", cfg.num_layers - n_dense),)
+    return (("dense_layers", n_dense),) + out if n_dense else out
+
+
 def param_shapes(cfg) -> dict:
-    d, v, l = cfg.d_model, cfg.vocab_size, cfg.num_layers
-    stacked = jax.tree_util.tree_map(
-        lambda s: (l, *s), layer_shapes(cfg),
-        is_leaf=lambda s: isinstance(s, tuple))
+    d, v = cfg.d_model, cfg.vocab_size
     shapes = {
         "embed": (v, d),
         "final_norm_scale": (d,),
-        "layers": stacked,
     }
+    for name, n in stacks(cfg):
+        shapes[name] = jax.tree_util.tree_map(
+            lambda s: (n, *s), layer_shapes(cfg, name == "dense_layers"),
+            is_leaf=lambda s: isinstance(s, tuple))
     if not cfg.tie_embeddings:
         shapes["lm_head"] = (d, v)
     return shapes
@@ -82,25 +93,33 @@ def _attn_full(lp, x, cfg, positions, impl):
     return out, kv
 
 
-def _ffn(lp, x, cfg):
-    if cfg.num_experts:
+def _ffn(lp, x, cfg, dropless=False, count_held=False):
+    """(out, aux, the (token, choice) pairs routed to held experts: 0
+    unless ``count_held`` and the layer has experts)."""
+    if "moe" in lp:
         y = constrain(x, "activation")
-        out, aux = moe_mod.moe_mlp(lp["moe"], y, cfg)
-        return out, aux
-    return gated_mlp(x, lp["mlp"], cfg.mlp_act), 0.0
+        if count_held:
+            return moe_mod.moe_mlp(lp["moe"], y, cfg, dropless=dropless,
+                                   count_held=True)
+        out, aux = moe_mod.moe_mlp(lp["moe"], y, cfg, dropless=dropless)
+        return out, aux, jnp.int32(0)
+    return gated_mlp(x, lp["mlp"], cfg.mlp_act), 0.0, jnp.int32(0)
 
 
-def block_full(lp, x, cfg, positions, impl):
+def block_full(lp, x, cfg, positions, impl, dropless=False):
     """One pre-norm layer over a full segment. Returns (x, aux, (k, v))."""
     h, kv = _attn_full(lp, rms_norm(x, lp["ln1_scale"], cfg.norm_eps), cfg,
                        positions, impl)
     x = x + h
-    f, aux = _ffn(lp, rms_norm(x, lp["ln2_scale"], cfg.norm_eps), cfg)
+    f, aux, _ = _ffn(lp, rms_norm(x, lp["ln2_scale"], cfg.norm_eps), cfg,
+                     dropless)
     return x + f, aux, kv
 
 
-def block_decode(lp, x, cfg, cache_l, pos):
-    """One layer, one token. cache_l: per-layer cache dict."""
+def block_decode(lp, x, cfg, cache_l, pos, count_held=False):
+    """One layer, one token. cache_l: per-layer cache dict.  Returns (x,
+    cache, the (token, choice) pairs routed to held experts, 0 unless
+    ``count_held``)."""
     xn = rms_norm(x, lp["ln1_scale"], cfg.norm_eps)
     if cfg.attn_kind == "mla":
         h, (ckv, kr) = mla_mod.mla_decode_attention(
@@ -113,8 +132,9 @@ def block_decode(lp, x, cfg, cache_l, pos):
             pos=pos)
         new_cache = {"k": k, "v": v}
     x = x + h
-    f, _ = _ffn(lp, rms_norm(x, lp["ln2_scale"], cfg.norm_eps), cfg)
-    return x + f, new_cache
+    f, _, held = _ffn(lp, rms_norm(x, lp["ln2_scale"], cfg.norm_eps), cfg,
+                      dropless=True, count_held=count_held)
+    return x + f, new_cache, held
 
 
 # --------------------------------------------------------------------------
@@ -126,7 +146,8 @@ def _embed_in(params, batch, cfg):
     else:
         tokens = batch["tokens"]
         x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
-        x = x * jnp.sqrt(float(cfg.d_model)).astype(x.dtype)
+        if cfg.embed_scale:
+            x = x * jnp.sqrt(float(cfg.d_model)).astype(x.dtype)
     return constrain(x, "activation")
 
 
@@ -152,7 +173,9 @@ def backbone(params, batch, cfg, *, impl="chunked", remat=False):
 
     if remat:
         body = jax.checkpoint(body)
-    (x, aux), _ = jax.lax.scan(body, (x, 0.0), params["layers"])
+    aux = 0.0
+    for name, _ in stacks(cfg):
+        (x, aux), _ = jax.lax.scan(body, (x, aux), params[name])
     return rms_norm(x, params["final_norm_scale"], cfg.norm_eps), aux
 
 
@@ -230,18 +253,23 @@ def train_loss(params, batch, cfg, *, impl="chunked"):
 # KV-cache: prefill & decode
 # --------------------------------------------------------------------------
 def cache_shapes(cfg, batch_size: int, max_len: int) -> dict:
-    """Shape/dtype tree of the decode cache (stacked over layers)."""
-    l, dtype = cfg.num_layers, jnp.dtype(cfg.dtype)
+    """Shape/dtype tree of the decode cache (stacked over layers, one
+    subtree per stack)."""
+    dtype = jnp.dtype(cfg.dtype)
     s = min(max_len, cfg.window) if cfg.window else max_len
-    if cfg.attn_kind == "mla":
-        layers = {
-            "ckv": ((l, batch_size, s, cfg.kv_lora_rank), dtype),
-            "kr": ((l, batch_size, s, cfg.qk_rope_dim), dtype),
-        }
-    else:
+
+    def stack(l):
+        if cfg.attn_kind == "mla":
+            return {
+                "ckv": ((l, batch_size, s, cfg.kv_lora_rank), dtype),
+                "kr": ((l, batch_size, s, cfg.qk_rope_dim), dtype),
+            }
         kv = (l, batch_size, s, cfg.num_kv_heads, cfg.head_dim)
-        layers = {"k": (kv, dtype), "v": (kv, dtype)}
-    return {"layers": layers, "pos": ((), jnp.int32)}
+        return {"k": (kv, dtype), "v": (kv, dtype)}
+
+    out = {name: stack(n) for name, n in stacks(cfg)}
+    out["pos"] = ((), jnp.int32)
+    return out
 
 
 def init_cache(cfg, batch_size: int, max_len: int) -> dict:
@@ -259,47 +287,52 @@ def prefill(params, batch, cfg, max_len: int, *, impl="chunked"):
 
     def body(carry, lp):
         h, aux = carry
-        h2, aux2, kv = block_full(lp, h, cfg, positions, impl)
+        h2, aux2, kv = block_full(lp, h, cfg, positions, impl, dropless=True)
         return (h2, aux + aux2), kv
-
-    (x, _aux), kvs = jax.lax.scan(body, (x, 0.0), params["layers"])
-    x = rms_norm(x, params["final_norm_scale"], cfg.norm_eps)
-    logits = _lm_head(params, x[:, -1:], cfg)
 
     cache = init_cache(cfg, b, max_len)
     cache["pos"] = jnp.asarray(s, jnp.int32)
-    cache_len = cache["layers"][next(iter(cache["layers"]))].shape[2]
-    if cfg.attn_kind == "mla":
-        ckv, kr = kvs
-        new = {"ckv": ckv, "kr": kr}
-    else:
-        k, v = kvs
-        new = {"k": k, "v": v}
-    for name, val in new.items():
-        if cfg.window and s >= cache_len:
-            # ring-buffer invariant: token p lives at slot p % window
-            seg = val[:, :, -cache_len:]
-            seg = jnp.roll(seg, shift=(s - cache_len) % cache_len, axis=2)
-        else:
-            seg = val
-        cache["layers"][name] = jax.lax.dynamic_update_slice_in_dim(
-            cache["layers"][name], seg.astype(cache["layers"][name].dtype),
-            0, axis=2)
+    carry = (x, 0.0)
+    for stack, _ in stacks(cfg):
+        carry, kvs = jax.lax.scan(body, carry, params[stack])
+        names = ("ckv", "kr") if cfg.attn_kind == "mla" else ("k", "v")
+        layer_cache = cache[stack]
+        for name, val in zip(names, kvs):
+            cache_len = layer_cache[name].shape[2]
+            if cfg.window and s >= cache_len:
+                # ring-buffer invariant: token p lives at slot p % window
+                seg = val[:, :, -cache_len:]
+                seg = jnp.roll(seg, shift=(s - cache_len) % cache_len,
+                               axis=2)
+            else:
+                seg = val
+            layer_cache[name] = jax.lax.dynamic_update_slice_in_dim(
+                layer_cache[name], seg.astype(layer_cache[name].dtype),
+                0, axis=2)
+    x = rms_norm(carry[0], params["final_norm_scale"], cfg.norm_eps)
+    logits = _lm_head(params, x[:, -1:], cfg)
     return logits, cache
 
 
-def decode_step(params, batch, cache, cfg):
-    """One decode step. batch: {"token": [B,1]}. Returns (logits, cache)."""
+def decode_step(params, batch, cache, cfg, *, count_held=False):
+    """One decode step. batch: {"token": [B,1]}. Returns (logits, cache);
+    ``count_held`` appends the (token, choice) pairs the step routed to
+    held experts, summed over the MoE layers."""
     x = _embed_in(params, {"tokens": batch["token"]}, cfg)
     pos = cache["pos"]
 
-    def body(h, lp_cache):
+    def body(carry, lp_cache):
+        h, held = carry
         lp, cache_l = lp_cache
-        h2, new_cache = block_decode(lp, h, cfg, cache_l, pos)
-        return h2, new_cache
+        h2, new_cache, held_l = block_decode(lp, h, cfg, cache_l, pos,
+                                             count_held)
+        return (h2, held + held_l), new_cache
 
-    x, new_layer_caches = jax.lax.scan(body, x,
-                                       (params["layers"], cache["layers"]))
-    x = rms_norm(x, params["final_norm_scale"], cfg.norm_eps)
+    new = {"pos": pos + 1}
+    carry = (x, jnp.int32(0))
+    for stack, _ in stacks(cfg):
+        carry, new[stack] = jax.lax.scan(body, carry,
+                                         (params[stack], cache[stack]))
+    x = rms_norm(carry[0], params["final_norm_scale"], cfg.norm_eps)
     logits = _lm_head(params, x, cfg)
-    return logits, {"layers": new_layer_caches, "pos": pos + 1}
+    return (logits, new, carry[1]) if count_held else (logits, new)

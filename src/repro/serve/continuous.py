@@ -19,6 +19,7 @@ against fresh link state instead of one plan per static batch).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -41,6 +42,19 @@ def _batch_dim_index(path_leafname: str) -> Optional[int]:
     if name in ("ssm", "conv") or name.startswith("m_"):
         return 2
     return None                      # pos etc.
+
+
+def _splice_rows(cache, cache1, slot):
+    """``cache`` with each per-slot leaf's row ``slot`` replaced by the
+    batch-1 ``cache1``'s; other leaves (``pos``, counts) kept."""
+    def put(path, big, small):
+        bdim = _batch_dim_index(str(getattr(path[-1], "key", path[-1])))
+        if bdim is None:
+            return big
+        return jax.lax.dynamic_update_slice_in_dim(
+            big, small.astype(big.dtype), slot, axis=bdim)
+
+    return jax.tree_util.tree_map_with_path(put, cache, cache1)
 
 
 class ContinuousBatchEngine:
@@ -75,8 +89,9 @@ class ContinuousBatchEngine:
                  clock=None, step_latency_s: float = 5e-3, obs=None,
                  metrics=None):
         assert cfg.family in ("dense", "moe", "vlm") \
-            and cfg.attn_kind == "gqa", \
-            "continuous batching requires the vector-position GQA decode path"
+            and cfg.attn_kind in ("gqa", "mla"), \
+            "continuous batching requires a vector-position decode path " \
+            "(GQA or MLA)"
         self.cfg = cfg
         self.api = build_model(cfg, impl="naive")
         self.slots = slots
@@ -98,7 +113,8 @@ class ContinuousBatchEngine:
         # live rolling quantiles: pass a repro.obs.MetricsRegistry and
         # the engine streams per-request sojourn / queue-wait into
         # mergeable sketches (summary kind) a /metrics scrape reads as
-        # p50/p90/p99 without stored samples
+        # p50/p90/p99 without stored samples, and counts each decode
+        # step's cache rows and (for MoE) its pairs on held experts
         self.metrics = metrics
         if metrics is not None:
             self._q_sojourn = metrics.quantile(
@@ -107,6 +123,14 @@ class ContinuousBatchEngine:
             self._q_wait = metrics.quantile(
                 "serve_queue_wait_seconds",
                 help="request queue wait (arrival to admission)")
+            self._c_rows = metrics.counter(
+                "serve_cache_rows_total",
+                help="cache rows the decode steps attend for live slots")
+            if cfg.num_experts:
+                self._c_pairs = metrics.counter(
+                    "serve_expert_pairs_held_total",
+                    help="(token, choice) pairs the decode steps route "
+                         "to held experts")
         self.replans = 0
         self.params = self.api.init_params(jax.random.key(seed))
         self.cache = self.api.init_cache(slots, max_len)
@@ -116,27 +140,21 @@ class ContinuousBatchEngine:
         self.slot_remaining = np.zeros(slots, np.int32)
         self.slot_last_tok = np.zeros(slots, np.int32)
         self._prefill1 = jax.jit(lambda p, b: self.api.prefill(p, b, max_len))
-        self._decode = jax.jit(self.api.decode_step, donate_argnums=(2,))
+        # with metrics, an MoE step also returns its pairs on held experts
+        self._count_held = metrics is not None and bool(cfg.num_experts)
+        decode = (functools.partial(self.api.decode_step, count_held=True)
+                  if self._count_held else self.api.decode_step)
+        self._decode = jax.jit(decode, donate_argnums=(2,))
+        self._splice_rows = jax.jit(_splice_rows, donate_argnums=(0,))
         self.steps = 0
         self.tokens_out = 0
 
     # -- cache splicing -----------------------------------------------------
     def _splice(self, slot: int, cache1):
         """Copy request-cache (batch=1) rows into ``slot`` of the shared
-        cache."""
-        flat_s, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        flat_1 = jax.tree_util.tree_leaves(cache1)
-        out = []
-        for (path, big), small in zip(flat_s, flat_1):
-            name = str(getattr(path[-1], "key", path[-1]))
-            bdim = _batch_dim_index(name)
-            if bdim is None:
-                out.append(big)
-                continue
-            idx = [slice(None)] * big.ndim
-            idx[bdim] = slice(slot, slot + 1)
-            out.append(big.at[tuple(idx)].set(small))
-        self.cache = jax.tree_util.tree_unflatten(treedef, out)
+        cache, in place (the shared cache is donated)."""
+        self.cache = self._splice_rows(self.cache, cache1,
+                                       jnp.asarray(slot, jnp.int32))
 
     # -- offload re-planning --------------------------------------------------
     def observe_link_bw(self) -> float:
@@ -210,10 +228,13 @@ class ContinuousBatchEngine:
             with self._region("decode"):
                 toks = jnp.asarray(self.slot_last_tok[:, None], jnp.int32)
                 self.cache["pos"] = jnp.asarray(self.slot_pos, jnp.int32)
-                logits, self.cache = self._decode(
-                    self.params, {"token": toks}, self.cache)
-                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                                 np.int32)
+                out = self._decode(self.params, {"token": toks}, self.cache)
+                logits, self.cache = out[:2]
+                top = jnp.argmax(logits[:, -1], axis=-1)
+                if self.metrics is None:
+                    nxt = np.asarray(top, np.int32)
+                else:
+                    nxt = self._count_step(top, out[2:])
             self.steps += 1
             self.clock.advance(self.step_latency_s)
             for s in range(self.slots):
@@ -247,6 +268,17 @@ class ContinuousBatchEngine:
                             f"req{req.rid}", req.arrived_at,
                             req.admitted_at, self.clock.now)
         return done
+
+    def _count_step(self, top, held) -> np.ndarray:
+        """The step's argmax, fetched in one transfer with its held-pair
+        count (``held``: that count, or empty for a dense model); counts
+        that and the step's cache rows (position + 1 of each live slot)."""
+        nxt, held = jax.device_get((top, held))
+        live = np.asarray([r is not None for r in self.slot_req])
+        self._c_rows.inc(float((self.slot_pos[live] + 1).sum()))
+        if held:
+            self._c_pairs.inc(float(held[0]))
+        return np.asarray(nxt, np.int32)
 
     @property
     def occupancy(self) -> float:
