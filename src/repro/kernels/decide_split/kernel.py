@@ -120,9 +120,23 @@ def decide_split_kernel(dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp,
     """``dcum``/``ecum``/``bvec`` [L+1] f32 split rows; env arrays [E]
     f32; ``spec`` [SPEC_LEN] f32.  Returns ``(split [E] int32, scalar
     cost [E] f32)``.
+
+    The kernel is built once per static signature: the operands' shapes
+    and dtypes with ``block_e``, ``block_s`` and ``interpret``.  Later
+    calls with the same signature dispatch the compiled program.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    return _decide_split(dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp,
+                         dev_w, edge_w, spec, block_e=block_e,
+                         block_s=block_s, interpret=interpret)
+
+
+def _decide_split(dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp, dev_w,
+                  edge_w, spec, *, block_e: int, block_s: int,
+                  interpret: bool):
+    """:func:`decide_split_kernel`'s pads, ``pallas_call`` and slices, as
+    one jitted program."""
     n_envs, n_splits = dev_div.shape[0], dcum.shape[0]
     block_e = min(block_e, max(n_envs, 1))
     block_s = min(block_s, n_splits)
@@ -164,6 +178,15 @@ def decide_split_kernel(dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp,
     )(spec, dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp, dev_w,
       edge_w)
     return split[:n_envs, 0], cost[:n_envs, 0]
+
+
+# XLA names a Pallas kernel's compiled instruction after the jitted
+# function around it.  Under this name the device trace shows the kernel
+# as it shows an eager pallas_call, ``%tpu_custom_call``, which is how
+# trace readers find the Pallas kernels.
+_decide_split.__name__ = "tpu_custom_call"
+_decide_split = jax.jit(_decide_split,
+                        static_argnames=("block_e", "block_s", "interpret"))
 
 
 def pack_spec(weights, radio_watts=0.0, price_per_edge_s=0.0,

@@ -68,8 +68,9 @@ def level_slots(counts) -> list[tuple[int, int]]:
 
 
 # The descent is written in lax, not jnp, with each constant array made
-# once: the kernel is traced anew on every call, and each jnp wrapper is a
-# jitted function that traces again.
+# once, which keeps the kernel's trace short: each jnp wrapper is a jitted
+# function that traces again.  The kernel is traced once per static
+# signature (see tree_predict_kernel), when a shape is first used.
 
 def _descend(codes, node_s, value_s, *, levels, thr_bits: int,
              feat_bits: int):
@@ -165,19 +166,34 @@ def tree_predict_kernel(codes, node, value, *, levels, n_trees: int,
     128]`` (``node`` int32 packed splits, ``value`` f32 leaf values
     pre-scaled by the learning rate) with their static ``levels`` ``((lo,
     width, has_split, has_leaf), ...)``.  Returns ``[N]`` f32 summed tree
-    outputs (add the ensemble ``base`` on the host)."""
+    outputs (add the ensemble ``base`` on the host).
+
+    The kernel is built once per static signature: the operands' shapes
+    and dtypes with ``levels``, ``n_trees``, ``thr_bits``, ``feat_bits``,
+    ``blk`` and ``interpret``.  Later calls with the same signature
+    dispatch the compiled program."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if codes.shape[0] == 0:              # nothing to grid over
+        return jnp.zeros((0,), jnp.float32)
+    return _tree_predict(codes, node, value, levels=tuple(levels),
+                         n_trees=n_trees, thr_bits=thr_bits,
+                         feat_bits=feat_bits, blk=blk,
+                         interpret=interpret)
+
+
+def _tree_predict(codes, node, value, *, levels, n_trees: int,
+                  thr_bits: int, feat_bits: int, blk: int, interpret: bool):
+    """:func:`tree_predict_kernel`'s pad, transpose, ``pallas_call`` and
+    slice, as one jitted program."""
     n, f = codes.shape
     n_chunks, n_slots, _ = node.shape
-    if n == 0:                           # nothing to grid over
-        return jnp.zeros((0,), jnp.float32)
     tile = min(ROW_TILE, -(-min(blk, n) // LANES) * LANES)
     blk = -(-min(blk, n) // tile) * tile
     pad = (-n) % blk
     codes_t = jnp.pad(codes, ((0, pad), (0, 0))).T           # [F, N + pad]
     nb = (n + pad) // blk
-    kernel = functools.partial(_kernel, levels=tuple(levels),
+    kernel = functools.partial(_kernel, levels=levels,
                                thr_bits=thr_bits, feat_bits=feat_bits,
                                n_trees=n_trees, n_chunks=n_chunks, tile=tile)
     slot_spec = pl.BlockSpec((pl.squeezed, n_slots, LANES),
@@ -196,3 +212,11 @@ def tree_predict_kernel(codes, node, value, *, levels, n_trees: int,
         interpret=interpret,
     )(codes_t, node, value)
     return out[0, :n]
+
+
+# named as the decide kernel's entry is, for the same reason: the device
+# trace shows the compiled kernel as ``%tpu_custom_call``
+_tree_predict.__name__ = "tpu_custom_call"
+_tree_predict = jax.jit(_tree_predict,
+                        static_argnames=("levels", "n_trees", "thr_bits",
+                                         "feat_bits", "blk", "interpret"))

@@ -12,7 +12,9 @@ host, leaving the scan multiply-free — nothing for XLA to contract).
 ``backend="pallas"`` calls the fused TPU kernel
 (:mod:`repro.kernels.tree_predict.kernel`): f32, within tolerance, over
 the ensemble's level layout (:func:`level_layout`, built once per
-ensemble), interpret mode off-TPU.
+ensemble), interpret mode off-TPU.  The kernel compiles once per static
+signature (row count, level layout, bit widths, block size), so repeated
+calls at one shape dispatch the compiled program.
 """
 # repro: module-tags=fma-sensitive
 # (DET001: the scan must stay multiply-free/add-only — a dot/matmul

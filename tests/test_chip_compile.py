@@ -56,6 +56,15 @@ def _hlo(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _traced_kernels(text: str) -> list[str]:
+    """The compiled HLO's Pallas kernels under the name a device trace
+    gives them, and by which its readers find them: instructions named
+    ``%tpu_custom_call``."""
+    lines = (ln.strip().removeprefix("ROOT ") for ln in text.splitlines())
+    return [ln for ln in lines
+            if ln.startswith("%tpu_custom_call") and "custom-call(" in ln]
+
+
 def test_decide_split_kernel_compiles(one_chip, no_compile_cache):
     """16384 envs × L+1 = 1025 splits at the ops defaults."""
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
@@ -65,6 +74,8 @@ def test_decide_split_kernel_compiles(one_chip, no_compile_cache):
     text = _hlo(fn, *[f32((1025,))] * 3, *[f32((16384,))] * 7,
                 f32((SPEC_LEN,)))
     assert "tpu_custom_call" in text
+    kernels = _traced_kernels(text)
+    assert len(kernels) == 1 and " = (s32[" in kernels[0]
 
 
 #: per-level node counts of the compiled layouts: a full depth-7 tree
@@ -99,6 +110,8 @@ def test_tree_predict_kernel_compiles(one_chip, no_compile_cache, shape):
                 sds((chunks, n_slots, LANES), jnp.int32),
                 sds((chunks, n_slots, LANES), jnp.float32))
     assert "tpu_custom_call" in text
+    kernels = _traced_kernels(text)
+    assert len(kernels) == 1 and " = f32[" in kernels[0]
 
 
 def test_decide_latency_f64_compiles(one_chip, no_compile_cache):

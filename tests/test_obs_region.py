@@ -2,8 +2,9 @@
 device-idle attribution that reads them (``repro.obs.analyze.idle``).
 
 A CPU profile of the Pallas decide and tree-predict paths (interpret
-mode) shows each phase span nested in its call span, with JAX's compile
-events inside the kernel span; the attribution, on traces made by hand,
+mode) shows each phase span nested in its call span, with the first
+call's compile events inside the kernel span and none under a second
+identical call's; the attribution, on traces made by hand,
 names idle gaps by the innermost span and splits each span's idle time
 into compile and the rest; and the spans perturb nothing: decisions,
 predictions and served tokens are bit-identical with a live ``Tracer``
@@ -110,7 +111,6 @@ def test_importing_obs_pulls_in_no_jax():
 # a CPU profile: phase spans nest in their call, compiles in the kernel
 # --------------------------------------------------------------------------
 def run_decide(gbt):
-    # a shape no other test builds, so the kernel compiles in the trace
     envs = envs_of(72)
     return dec.decide_all(layers_of(7), envs, backend="pallas",
                           cost=co.PredictorCost(gbt, DEVICE, EDGE))
@@ -141,9 +141,12 @@ def inside(inner, outer) -> bool:
 @pytest.mark.parametrize("path", sorted(PHASES))
 def test_profile_nests_phases_in_the_call(path, gbt, tmp_path):
     run, call, phases, kernel = PHASES[path]
-    with profiled(tmp_path):
-        run(gbt)
-    trace = idle.load(str(tmp_path))
+    jax.clear_caches()                  # the first call builds the kernel
+    first, second = tmp_path / "first", tmp_path / "second"
+    for trace_dir in (first, second):
+        with profiled(trace_dir):
+            run(gbt)
+    trace = idle.load(str(first))
     calls = [s for s in trace.spans if s[0] == call]
     assert len(calls) == 1
     for name in phases:
@@ -158,7 +161,11 @@ def test_profile_nests_phases_in_the_call(path, gbt, tmp_path):
     assert rep["program"][kernel]["compiles"] >= 1
     assert rep["program"][call]["compiles"] >= \
         rep["program"][kernel]["compiles"]
-    assert analyze_main(["idle", str(tmp_path)]) == 0
+    assert analyze_main(["idle", str(first)]) == 0
+    # the same call again dispatches the kernel built by the first
+    again = idle.attribute(idle.load(str(second)))
+    assert again["program"][kernel]["count"] == 1
+    assert again["program"][kernel]["compiles"] == 0
 
 
 def test_profile_records_make_envs(tmp_path):
