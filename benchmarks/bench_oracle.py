@@ -14,8 +14,9 @@ baseline at the repo root):
     smoke, not a performance number — and are flagged
     ``interpret: true``.
   * **predictor-driven decide** — ``decide_all(cost=PredictorCost(...))``
-    throughput per backend at the 16384-env fleet size (the PR-3 sweep,
-    now with the profiling model in the loop).
+    throughput on numpy and Pallas at the 16384-env fleet size (the
+    PR-3 sweep, now with the profiling model in the loop; Pallas rows
+    off-TPU are interpret-mode and flagged so).
   * **closed-loop drift** — a structured machine-slowdown scenario:
     observations stream through an ``OnlineOracle``; rolling nRMSE
     degrades at the change point, Page–Hinkley triggers, the
@@ -147,6 +148,8 @@ def bench_predict(smoke: bool) -> list[dict]:
 # predictor-driven decide sweep
 # --------------------------------------------------------------------------
 def bench_decide(smoke: bool) -> list[dict]:
+    import jax
+    interpret = jax.default_backend() != "tpu"
     reps = 3 if smoke else 7
     n_envs = 4096 if smoke else 16384
     layers = synth_layers(64)
@@ -156,7 +159,7 @@ def bench_decide(smoke: bool) -> list[dict]:
                          link_bw=np.geomspace(1e5, 1e10, n_envs),
                          input_bytes=1e5)
     rows, cell = [], {}
-    for backend in ("numpy", "jax"):
+    for backend in ("numpy", "pallas"):
         cost = co.PredictorCost(model, device, edge)
         t, best = times_us(lambda: dec.decide_all(layers, envs, cost=cost,
                                                   backend=backend), reps)
@@ -171,6 +174,7 @@ def bench_decide(smoke: bool) -> list[dict]:
             "decisions_per_s": n_envs * 1e6 / t,
         }
         if backend != "numpy":
+            row["interpret"] = interpret
             row["speedup_vs_numpy"] = cell["numpy"] / best
         rows.append(row)
     return rows

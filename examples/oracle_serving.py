@@ -2,7 +2,7 @@
 
 1. Fit the paper's profiling GBT on (layer, hardware) features.
 2. Run a 16384-environment predictor-driven offloading sweep on the
-   accelerator backend (the fitted trees execute as jitted XLA).
+   Pallas decide kernel (the fitted trees execute as jitted XLA).
 3. Stream realised execution times through an OnlineOracle while two
    device classes silently slow down 3x: watch the rolling nRMSE
    degrade, the Page-Hinkley detector fire, and a fresh-window refit
@@ -53,13 +53,13 @@ def main():
                          input_bytes=1e7)
     cost = co.PredictorCost(gbt, DEVICE, EDGE)
     plan_np = dec.decide_all(layers, envs, cost=cost)
-    plan_jx = dec.decide_all(layers, envs, cost=cost, backend="jax")
-    assert np.array_equal(plan_np.splits, plan_jx.splits)
-    on_dev = np.bincount(np.minimum(plan_jx.splits, 2), minlength=3)
-    print(f"  16384 envs swept on backend='jax'; splits exactly match "
+    plan_pl = dec.decide_all(layers, envs, cost=cost, backend="pallas")
+    assert np.array_equal(plan_np.splits, plan_pl.splits)
+    on_dev = np.bincount(np.minimum(plan_pl.splits, 2), minlength=3)
+    print(f"  16384 envs swept on backend='pallas'; splits exactly match "
           f"numpy\n  all-edge: {on_dev[0]}, partial: "
-          f"{len(envs) - on_dev[0] - (plan_jx.splits == len(layers)).sum()},"
-          f" all-device: {(plan_jx.splits == len(layers)).sum()}")
+          f"{len(envs) - on_dev[0] - (plan_pl.splits == len(layers)).sum()},"
+          f" all-device: {(plan_pl.splits == len(layers)).sum()}")
 
     print("\n== 3. online drift -> detection -> refit -> recovery ==")
     oracle = OnlineOracle(gbt, DEVICE, EDGE, window=256, min_refit=120,

@@ -1,10 +1,10 @@
 """Determinism rules: bit-for-bit and virtual-clock contracts.
 
-DET001 — the numpy ≡ jax f64 bit-for-bit guarantee (PR 3) exists only
-because the scalarisation / cumulative-sum paths accumulate term by term
-with one eager primitive per step; a ``@`` / ``dot`` / ``matmul`` lets
-BLAS or XLA fuse multiply-adds (FMA contraction) and the two backends
-round differently.  Modules that carry this guarantee declare it with a
+DET001 — the numpy ≡ jax f64 bit-for-bit guarantees (the jitted tree
+predict, the sharded decide) exist only because their scalarisation /
+cumulative-sum paths accumulate term by term; a ``@`` / ``dot`` /
+``matmul`` lets BLAS or XLA fuse multiply-adds (FMA contraction) and
+the two sides round differently.  Modules that carry this guarantee declare it with a
 ``# repro: module-tags=fma-sensitive`` directive and this rule keeps
 them honest.
 

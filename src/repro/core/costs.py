@@ -163,11 +163,11 @@ def scalarize_weighted(components: np.ndarray,
     """Weighted sum over the trailing objective axis (see
     :func:`weight_vector` for the weight semantics).
 
-    Accumulated term-by-term in objective order rather than via ``@``: the
-    accelerator backends (``repro.kernels.decide_split``) replay the exact
-    same multiply/add sequence with one eager jnp primitive per step, which
-    is what keeps ``backend="jax"`` scalarisations bit-for-bit equal to
-    this host path in f64 (BLAS dot kernels round differently).
+    Accumulated term-by-term in objective order rather than via ``@``, so
+    the result does not depend on the operand's shape: the Pallas path's
+    f64 re-evaluation at the chosen splits (``[E, K]``) scores each
+    split exactly as the host sweep's ``[E, L+1, K]`` tensor does (BLAS
+    dot kernels round differently per shape).
     """
     comp = np.asarray(components, np.float64)
     w = weight_vector(objectives, weights)
@@ -180,7 +180,7 @@ def scalarize_weighted(components: np.ndarray,
 
 
 # --------------------------------------------------------------------------
-# Accelerator lowering: the scalar spec the jit/Pallas kernels consume
+# Accelerator lowering: the scalar spec the Pallas decide kernel consumes
 # --------------------------------------------------------------------------
 #: canonical objective order of the accelerator decision kernels
 ACCEL_OBJECTIVES = ("latency_s", "energy_j", "price", "deadline_slack_s")
@@ -190,16 +190,16 @@ ACCEL_OBJECTIVES = ("latency_s", "energy_j", "price", "deadline_slack_s")
 class AccelSpec:
     """Parameters that fully determine a lowerable cost model.
 
-    The jit/Pallas decision kernels (``repro.kernels.decide_split``)
-    evaluate one fixed objective stack — latency, energy, price, deadline
-    slack, in :data:`ACCEL_OBJECTIVES` order — and scalarise it with
+    The Pallas decide kernel (``repro.kernels.decide_split``) evaluates
+    one fixed objective stack — latency, energy, price, deadline slack,
+    in :data:`ACCEL_OBJECTIVES` order — and scalarises it with
     ``weights``.  Latency-only models are the ``weights = (1, 0, 0, 0)``
     special case.  *Where* per-layer compute times come from is the
     ``lowered`` seam: ``None`` means the analytic roofline (``flops /
     (peak × efficiency)`` from the shared ``EnvArrays`` tensors);
     otherwise it is a :class:`repro.oracle.lowered.LoweredLayerTimes` —
     a fitted profiling regressor compiled to array form, whose
-    environment-invariant ``(t_dev, t_edge)`` vectors the kernels turn
+    environment-invariant ``(t_dev, t_edge)`` vectors the kernel turns
     into cumulative-split times on-device.
     """
     efficiency: float
@@ -247,7 +247,7 @@ def lower_to_accel(cost: Optional[CostModel],
     if fn is None:
         raise TypeError(
             f"{type(cost).__name__} does not lower to the accelerator "
-            "decision kernels: backend='jax'/'pallas' needs pure array "
+            "decision kernel: backend='pallas' needs pure array "
             "math over EnvArrays or a lowerable fitted regressor "
             "(AnalyticCost, CompositeCost, PredictorCost over a ridge/"
             "MLP/GBT model) — use backend='numpy'")

@@ -18,19 +18,20 @@ stacks (``CompositeCost``).  Without ``cost=`` the historical analytic
 latency-only behaviour is preserved bit-for-bit.
 
 *Where* the sweep runs is also pluggable: ``decide_all``/``sweep_links``
-take ``backend="numpy" | "jax" | "pallas"``.  ``"numpy"`` (default) is
-this module's host path; ``"jax"`` lowers the same pipeline to jitted XLA
-(``repro.kernels.decide_split.ops``), bit-for-bit equal in f64, so
-serving engines can re-plan on-accelerator next to the model; ``"pallas"``
-is a fused TPU kernel for very large sweeps that never materialises the
-``[n_envs, L+1]`` cost tensor in HBM (within f32 tolerance).  Cost
-models lower via ``costs.lower_to_accel``: ``AnalyticCost`` and
-``CompositeCost`` are pure array math over ``EnvArrays``;
-``PredictorCost`` lowers by compiling its fitted regressor to array
-form (``repro.oracle.lowered`` — ridge → dot, MLP → jitted matmul
-chain, GBT → the ``tree_predict`` kernels), so predictor-driven sweeps
-run on-accelerator too.  Only regressors outside those families raise
-``TypeError`` on accelerator backends rather than silently copying back.
+take ``backend="numpy" | "pallas"``.  ``"numpy"`` (default) is this
+module's host path and the reference every other path is tested
+against; ``"pallas"`` is a fused TPU kernel
+(``repro.kernels.decide_split``) that never materialises the
+``[n_envs, L+1]`` cost tensor in HBM and re-costs the chosen splits in
+f64 (within f32-argmin tolerance).  Cost models lower via
+``costs.lower_to_accel``: ``AnalyticCost`` and ``CompositeCost`` are
+pure array math over ``EnvArrays``; ``PredictorCost`` lowers by
+compiling its fitted regressor to array form (``repro.oracle.lowered`` —
+ridge → dot, MLP → jitted matmul chain, GBT → the ``tree_predict``
+kernels), so predictor-driven sweeps run on-accelerator too.  Only
+regressors outside those families raise ``TypeError`` on ``"pallas"``
+rather than silently copying back.  The multi-chip path is
+``repro.sim.decide_all_sharded``.
 
 Usage::
 
@@ -48,7 +49,6 @@ Usage::
     plan.splits, plan.total_time_s              # [4096] each
     plan[0]                                     # -> offload.SplitDecision
 
-    dec.decide_all(layers, envs, backend="jax")     # jitted, bit-for-bit
     dec.decide_all(layers, envs, backend="pallas")  # fused TPU kernel
 
     cost = co.CompositeCost(weights={"latency_s": 1, "energy_j": 0.05})
@@ -132,6 +132,29 @@ def stack_envs(envs: Sequence[OffloadEnv]) -> EnvArrays:
         np.asarray([e.input_bytes for e in envs], np.float64),
         np.asarray([e.device.tdp_watts for e in envs], np.float64),
         np.asarray([e.edge.tdp_watts for e in envs], np.float64))
+
+
+def layer_arrays(layers: Sequence[LayerCost]
+                 ) -> tuple[np.ndarray, np.ndarray]:
+    """``(flops, act_bytes)`` of a layer chain, each f64 ``[L]``."""
+    n = len(layers)
+    flops = np.fromiter((lc.flops for lc in layers), np.float64, count=n)
+    act = np.fromiter((lc.act_bytes for lc in layers), np.float64, count=n)
+    return flops, act
+
+
+def env_columns(envs: EnvArrays) -> tuple[np.ndarray, ...]:
+    """The seven f64 ``[E]`` columns of ``envs`` in field order, board
+    power zero where ``envs`` carries none."""
+    e = len(envs)
+
+    def tdp(x):
+        return np.zeros(e) if x is None else np.asarray(x, np.float64)
+
+    return tuple(np.asarray(x, np.float64) for x in
+                 (envs.dev_flops, envs.edge_flops, envs.link_bw,
+                  envs.link_latency_s, envs.input_bytes)) \
+        + (tdp(envs.dev_tdp_watts), tdp(envs.edge_tdp_watts))
 
 
 def transfer_bytes(layers: Sequence[LayerCost], envs: EnvArrays
@@ -223,10 +246,6 @@ class DecisionPlan:
         return self.components[:, self.objectives.index(name)]
 
 
-# the pre-CostModel name, kept for existing callers
-BatchDecisions = DecisionPlan
-
-
 def decide_all(layers: Sequence[LayerCost], envs: EnvArrays,
                efficiency: float = EFFICIENCY, *,
                cost=None, backend: str = "numpy") -> DecisionPlan:
@@ -241,9 +260,8 @@ def decide_all(layers: Sequence[LayerCost], envs: EnvArrays,
     rather than silently ignoring one.
 
     ``backend`` selects where the sweep runs: ``"numpy"`` on the host
-    (default), ``"jax"`` as jitted XLA (bit-for-bit with numpy in f64),
-    ``"pallas"`` as the fused TPU kernel for very large sweeps (within
-    f32 tolerance) — see :mod:`repro.kernels.decide_split`.
+    (default), ``"pallas"`` as the fused TPU kernel (within f32
+    tolerance) — see :mod:`repro.kernels.decide_split`.
     ``None``/``AnalyticCost``/``CompositeCost`` lower as pure array
     math; ``PredictorCost`` lowers through its compiled regressor
     (``repro.oracle.lowered``) and only raises when the wrapped model
@@ -254,10 +272,12 @@ def decide_all(layers: Sequence[LayerCost], envs: EnvArrays,
             raise ValueError(
                 "efficiency= is ignored when cost= is given; set it on the "
                 "cost model instead (e.g. AnalyticCost(efficiency=...))")
-        if backend != "numpy":
+        if backend == "pallas":
             from repro.kernels.decide_split import ops
-            return ops.decide_accel(layers, envs, efficiency, cost=cost,
-                                    backend=backend)
+            return ops.decide_pallas(layers, envs, efficiency, cost=cost)
+        if backend != "numpy":
+            raise ValueError(f"unknown decide backend {backend!r}; "
+                             "expected 'numpy' or 'pallas'")
         if cost is None:
             dev_cum, xfer, edge_cum = latency_components(layers, envs,
                                                          efficiency)

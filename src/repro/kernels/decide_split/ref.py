@@ -1,6 +1,6 @@
 """Numpy reference for the decision kernels: the host decision core.
 
-The oracle the jit/Pallas backends are pinned against is simply the
+The oracle the Pallas path is pinned against is simply the
 existing vectorized host path — ``latency_matrix`` + row argmin for the
 analytic default, and the ``CostModel`` component/scalarise pipeline for
 multi-objective decisions.  Kept as a thin delegation (not a copy) so the
@@ -26,7 +26,7 @@ def latency_matrix_ref(layers: Sequence[LayerCost], envs: dec.EnvArrays,
 def decide_ref(layers: Sequence[LayerCost], envs: dec.EnvArrays,
                efficiency: float = DEFAULT_EFFICIENCY, *,
                cost=None) -> dec.DecisionPlan:
-    """Host ``decide_all`` — the semantics the accelerated backends must
-    reproduce (bit-for-bit for jax/f64, within tolerance for Pallas)."""
+    """Host ``decide_all`` — the semantics the Pallas path must reproduce
+    within f32-argmin tolerance."""
     return dec.decide_all(layers, envs, efficiency, cost=cost,
                           backend="numpy")

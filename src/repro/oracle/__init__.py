@@ -3,14 +3,14 @@ profiling-in-the-loop.
 
 Closes the paper's loop end to end: fitted profiling regressors are
 *compiled to pure array form* (``lowered``) so predictor-driven
-offloading sweeps run on ``backend="jax"``/``"pallas"`` next to the
-model; live ``(features, realised time)`` observations from the
-streaming simulator feed an ``OnlineOracle`` (``online``) that applies
-an always-on cheap residual correction, detects drift with a
-Page–Hinkley test on normalised residuals, and refits on trigger; and a
-versioned ``PredictorRegistry`` (``registry``) snapshots every
-published model with an atomic current-pointer swap so serving never
-observes a half-written predictor.
+offloading sweeps run on ``backend="pallas"`` next to the model; live
+``(features, realised time)`` observations from the streaming simulator
+feed an ``OnlineOracle`` (``online``) that applies an always-on cheap
+residual correction, detects drift with a Page–Hinkley test on
+normalised residuals, and refits on trigger; and a versioned
+``PredictorRegistry`` (``registry``) snapshots every published model
+with an atomic current-pointer swap so serving never observes a
+half-written predictor.
 
 Seams (pinned by ``tests/test_oracle.py``):
 

@@ -17,10 +17,10 @@ regressor family onto an equivalent array program —
     fused Pallas batched tree-inference kernel within f32 tolerance) —
 
 and :class:`LoweredLayerTimes` packages the lowered model together with
-a ``PredictorCost``'s feature function so the accelerator decision
-backends (:mod:`repro.kernels.decide_split.ops`) can reconstruct the
-per-layer device/edge time vectors on their own, which is what lets
-``decide_all(cost=PredictorCost(...), backend="jax"|"pallas")`` run
+a ``PredictorCost``'s feature function so the accelerator decide path
+(:mod:`repro.kernels.decide_split.ops`) can reconstruct the
+per-layer device/edge time vectors on its own, which is what lets
+``decide_all(cost=PredictorCost(...), backend="pallas")`` run
 predictor-driven sweeps without ever calling back into host Python.
 
 Models outside these families still raise ``TypeError`` from

@@ -65,8 +65,8 @@ class ContinuousBatchEngine:
     current ``link_bw`` observation (a float, or a zero-arg callable
     returning the observed bytes/s) and recorded on ``request.offload``.
     ``decision_backend`` selects where those re-planning sweeps run
-    (``"numpy"`` host default, ``"jax"`` jitted next to the model) — see
-    :func:`repro.core.decisions.decide_all`.
+    (``"numpy"`` host default, ``"pallas"`` the fused kernel next to the
+    model) — see :func:`repro.core.decisions.decide_all`.
 
     Admission is clocked: the engine keeps a virtual
     :class:`repro.sim.events.Clock` that advances ``step_latency_s``

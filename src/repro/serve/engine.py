@@ -61,8 +61,8 @@ class ServeEngine:
     it becomes the default cost model for :meth:`offload_plan`, so one
     engine can plan against analytic, predictor-driven, or multi-objective
     costs without per-call plumbing.  ``decision_backend`` picks where
-    re-planning sweeps run (``"numpy"`` host default, ``"jax"`` jitted
-    next to the model, ``"pallas"`` fused kernel) — see
+    re-planning sweeps run (``"numpy"`` host default, ``"pallas"`` the
+    fused kernel next to the model) — see
     :func:`repro.core.decisions.decide_all`.
     """
 

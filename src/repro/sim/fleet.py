@@ -16,8 +16,8 @@ inside which every per-node bandwidth is constant:
     one broadcast over the slab's effective-bandwidth row;
   * offload splits: all tasks admitted in a slab that share a layer
     chain are decided in ONE ``decide_all`` call (``split_backend=``
-    picks ``"numpy"``/``"jax"``/``"pallas"`` or ``"sharded"``, which
-    runs the env axis ``shard_map``-sharded across the device mesh);
+    picks ``"numpy"``/``"pallas"`` or ``"sharded"``, which runs the env
+    axis ``shard_map``-sharded across the device mesh);
   * completions: telemetry lands as column batches
     (:meth:`repro.sim.telemetry.Telemetry.complete_arrays`), ordered by
     the exact (finish time, placement sequence) pop order of the heap.
@@ -42,6 +42,9 @@ sequential features are rejected rather than silently diverging:
 ``cost=`` models (arbitrary host callables per arrival) all need
 ``engine="event"``.
 """
+# repro: module-tags=fma-sensitive
+# (DET001: the sharded decide is bit-for-bit with the numpy decide_all in
+#  f64; a @ / dot / matmul here would let XLA FMA-contract and break that)
 from __future__ import annotations
 
 import dataclasses
@@ -143,6 +146,53 @@ def _place_singleton_run(avail, peak_eff, ts, fls, ibs, bwc_rows, segs):
     return tuple(np.concatenate([o[k] for o in outs]) for k in range(4))
 
 
+def _seq_cumsum(x):
+    """Row-wise cumsum via a sequential scan: numpy's exact float ordering
+    (XLA's native cumsum is a parallel prefix with different rounding)."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(carry, col):
+        carry = carry + col
+        return carry, carry
+
+    _, out = jax.lax.scan(step, jnp.zeros(x.shape[:1], x.dtype), x.T)
+    return out.T
+
+
+def _latency_parts(flops, act, dev, edge, bw, lat, inp, eff):
+    """jnp twin of ``decisions.latency_components``: ``(dev_cum, xfer,
+    edge_cum)``, each ``[E, L+1]``, in numpy's float ordering."""
+    import jax.numpy as jnp
+    e, n = dev.shape[0], flops.shape[0]
+    t_dev = flops[None, :] / (dev[:, None] * eff)
+    t_edge = flops[None, :] / (edge[:, None] * eff)
+    zero = jnp.zeros((e, 1), t_dev.dtype)
+    dev_cum = jnp.concatenate([zero, _seq_cumsum(t_dev)], axis=1)
+    edge_cum = jnp.concatenate(
+        [_seq_cumsum(t_edge[:, ::-1])[:, ::-1], zero], axis=1)
+    tb = jnp.concatenate(
+        [inp[:, None], jnp.broadcast_to(act[None, :], (e, n))], axis=1)
+    tb = tb.at[:, -1].set(0.0)                  # split == L ships nothing
+    xfer = lat[:, None] + tb / jnp.maximum(bw, 1.0)[:, None]
+    xfer = xfer.at[:, -1].set(0.0)
+    return dev_cum, xfer, edge_cum
+
+
+def _decide_latency(flops, act, dev, edge, bw, lat, inp, eff):
+    """The analytic latency-only decide one shard runs: ``(split, total,
+    dev, xfer, edge)`` at each env's argmin, bit-for-bit (f64) with the
+    numpy ``decide_all``."""
+    import jax.numpy as jnp
+    dev_cum, xfer, edge_cum = _latency_parts(flops, act, dev, edge, bw,
+                                             lat, inp, eff)
+    total = dev_cum + xfer + edge_cum
+    s = jnp.argmin(total, axis=1)
+    rows = jnp.arange(dev.shape[0])
+    return s, total[rows, s], dev_cum[rows, s], xfer[rows, s], \
+        edge_cum[rows, s]
+
+
 @functools.lru_cache(maxsize=8)
 def sharded_latency_fn(mesh, efficiency: float = DEFAULT_EFFICIENCY):
     """The jitted latency decide with its environment axis sharded over
@@ -154,11 +204,9 @@ def sharded_latency_fn(mesh, efficiency: float = DEFAULT_EFFICIENCY):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.kernels.decide_split import ops
-
     env_spec = P(tuple(mesh.axis_names))              # env axis over all
     return jax.jit(jax.shard_map(
-        lambda f, a, d, e, b, l, i: ops._decide_latency(
+        lambda f, a, d, e, b, l, i: _decide_latency(
             f, a, d, e, b, l, i, efficiency),
         mesh=mesh,
         in_specs=(P(), P()) + (env_spec,) * 5,
@@ -171,12 +219,11 @@ def decide_all_sharded(layers, envs: dec.EnvArrays,
                        mesh=None) -> dec.DecisionPlan:
     """``decide_all`` with the environment axis sharded across devices.
 
-    Runs :func:`sharded_latency_fn` over ``mesh`` (default: the repo's
-    debug mesh over every visible device; a single device falls back to
-    the plain jit path), with the env arrays placed shard by shard, the
-    env axis padded to the shard count with
-    :func:`repro.core.decisions.pad_envs` and the results trimmed.  The
-    maths is row-wise, so the result is bit-for-bit (f64) the numpy/jax
+    Runs :func:`sharded_latency_fn` over ``mesh`` (default: one axis
+    over every visible device, a single device included), with the env
+    arrays placed shard by shard, the env axis padded to the shard count
+    with :func:`repro.core.decisions.pad_envs` and the results trimmed.
+    The maths is row-wise, so the result is bit-for-bit (f64) the numpy
     ``decide_all`` wherever f64 is native.
     """
     import jax
@@ -184,18 +231,13 @@ def decide_all_sharded(layers, envs: dec.EnvArrays,
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro.kernels.decide_split import ops
     from repro.x64 import x64
 
     if mesh is None:
-        n_dev = jax.device_count()
-        if n_dev < 2:
-            return dec.decide_all(layers, envs, efficiency, backend="jax")
-        from repro.launch.mesh import make_debug_mesh
-        mesh = make_debug_mesh(n_dev)
+        mesh = jax.make_mesh((jax.device_count(),), ("env",))
     padded, n_orig = dec.pad_envs(envs, mesh.size)
-    flops, act = ops._layer_arrays(layers)
-    dev, edge, bw, lat, inp, _, _ = ops._env_arrays(padded)
+    flops, act = dec.layer_arrays(layers)
+    dev, edge, bw, lat, inp, _, _ = dec.env_columns(padded)
     rows = NamedSharding(mesh, P())
     env_axis = NamedSharding(mesh, P(tuple(mesh.axis_names)))
     with x64():
@@ -213,8 +255,8 @@ def _split_decide(layers, envs, cost, backend) -> dec.DecisionPlan:
     if backend == "sharded":
         if cost is not None:
             raise ValueError("split_backend='sharded' supports the "
-                             "analytic cost only (cost models lower via "
-                             "backend='jax')")
+                             "analytic cost only (cost models run on "
+                             "split_backend='pallas')")
         return decide_all_sharded(layers, envs)
     return dec.decide_all(layers, envs, cost=cost, backend=backend)
 

@@ -337,7 +337,7 @@ def simulate_stream(tasks: Sequence[sch.Task], arrivals,
     offload split is decided once via :func:`repro.core.decisions.
     decide_all` against the link observation at its arrival (optionally
     under ``split_cost=``; ``split_backend=`` picks ``"numpy"`` /
-    ``"jax"`` / ``"pallas"`` / ``"sharded"``) and recorded on its
+    ``"pallas"`` / ``"sharded"``) and recorded on its
     :class:`TaskRecord` — the commit-at-admission baseline the Pareto
     planner is scored against, and the slab-batchable decision path the
     fleet engine drains in bulk.

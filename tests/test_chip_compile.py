@@ -15,10 +15,11 @@ import pytest
 jax = pytest.importorskip("jax")
 jnp = jax.numpy
 
-from repro.kernels.decide_split import ops as decide_ops  # noqa: E402
 from repro.kernels.decide_split.kernel import (SPEC_LEN,  # noqa: E402
                                                decide_split_kernel)
+from repro.kernels.decide_split.ops import BLOCK_E, BLOCK_S  # noqa: E402
 from repro.kernels.tree_predict.kernel import tree_predict_kernel  # noqa: E402
+from repro.sim import fleet  # noqa: E402
 from repro.x64 import x64  # noqa: E402
 
 
@@ -66,11 +67,11 @@ def _traced_kernels(text: str) -> list[str]:
 
 
 def test_decide_split_kernel_compiles(one_chip, no_compile_cache):
-    """16384 envs × L+1 = 1025 splits at the ops defaults."""
+    """16384 envs × L+1 = 1025 splits at the ops block sizes."""
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
                             sharding=one_chip)
-    fn = functools.partial(decide_split_kernel, block_e=256, block_s=128,
-                           interpret=False)
+    fn = functools.partial(decide_split_kernel, block_e=BLOCK_E,
+                           block_s=BLOCK_S, interpret=False)
     text = _hlo(fn, *[f32((1025,))] * 3, *[f32((16384,))] * 7,
                 f32((SPEC_LEN,)))
     assert "tpu_custom_call" in text
@@ -115,10 +116,10 @@ def test_tree_predict_kernel_compiles(one_chip, no_compile_cache, shape):
 
 
 def test_decide_latency_f64_compiles(one_chip, no_compile_cache):
-    """The jax backend's f64 decide, which the chip emulates."""
+    """The sharded decide's f64 body, which the chip emulates."""
     with x64():
         f64 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float64,
                                 sharding=one_chip)
-        text = _hlo(functools.partial(decide_ops._decide_latency, eff=0.4),
+        text = _hlo(functools.partial(fleet._decide_latency, eff=0.4),
                     *[f64((64,))] * 2, *[f64((16384,))] * 5)
     assert "f64" in text

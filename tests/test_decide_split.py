@@ -1,11 +1,11 @@
-"""On-accelerator decision kernels vs the host decision core.
+"""The Pallas decide path and the sharded decide vs the host decision core.
 
-Pins ``backend="jax"`` bit-for-bit (f64) to the numpy path — splits,
-every DecisionPlan field, and the full latency matrix — and the fused
-Pallas kernel within f32 tolerance (plus a near-optimality bound, so a
-last-ulp argmin flip at a genuine tie cannot flake the suite).  Also
-covers the degenerate shapes every backend must accept (empty layer
-chain, zero environments) and the cost models that must *not* lower.
+Pins the fused Pallas kernel within f32 tolerance of the numpy path
+(plus a near-optimality bound, so a last-ulp argmin flip at a genuine
+tie cannot flake the suite), and ``decide_all_sharded`` on a one-device
+mesh bit-for-bit (f64) to it.  Also covers the degenerate shapes every
+path must accept (empty layer chain, zero environments) and the cost
+models and backends that must *not* lower.
 """
 import numpy as np
 import pytest
@@ -15,8 +15,8 @@ from repro.core import costs as co
 from repro.core import decisions as dec
 from repro.core import offload as off
 from repro.hw import EDGE_DEVICES, get_device
-from repro.kernels.decide_split import ops
 from repro.kernels.decide_split.ref import decide_ref, latency_matrix_ref
+from repro.sim import decide_all_sharded
 
 PLAN_FIELDS = ("splits", "total_time_s", "device_time_s",
                "transfer_time_s", "edge_time_s")
@@ -58,47 +58,6 @@ def assert_plans_equal(a, b):
             assert np.array_equal(x, y), f
 
 
-# --------------------------------------------------------------------------
-# jax backend: bit-for-bit with the numpy reference (f64)
-# --------------------------------------------------------------------------
-@pytest.mark.parametrize("trial", range(8))
-def test_jax_decide_bit_for_bit(trial):
-    rng = np.random.default_rng(trial)
-    layers = rand_layers(rng, int(rng.integers(1, 24)))
-    envs = rand_envs(rng, int(rng.integers(1, 48)))
-    assert_plans_equal(decide_ref(layers, envs),
-                       dec.decide_all(layers, envs, backend="jax"))
-
-
-def test_jax_latency_matrix_bit_for_bit():
-    rng = np.random.default_rng(3)
-    layers = rand_layers(rng, 19)
-    envs = rand_envs(rng, 31)
-    assert np.array_equal(ops.latency_matrix_jax(layers, envs),
-                          latency_matrix_ref(layers, envs))
-
-
-def test_jax_custom_efficiency_bit_for_bit():
-    rng = np.random.default_rng(4)
-    layers = rand_layers(rng, 9)
-    envs = rand_envs(rng, 12)
-    assert_plans_equal(dec.decide_all(layers, envs, 0.71),
-                       dec.decide_all(layers, envs, 0.71, backend="jax"))
-
-
-@pytest.mark.parametrize("make_cost", [co.AnalyticCost,
-                                       lambda: co.AnalyticCost(0.5),
-                                       composite],
-                         ids=["analytic", "analytic_eff", "composite"])
-def test_jax_cost_models_bit_for_bit(make_cost):
-    rng = np.random.default_rng(5)
-    layers = rand_layers(rng, 14)
-    envs = rand_envs(rng, 20)
-    assert_plans_equal(
-        dec.decide_all(layers, envs, cost=make_cost()),
-        dec.decide_all(layers, envs, cost=make_cost(), backend="jax"))
-
-
 def test_sweep_links_backend_passthrough():
     rng = np.random.default_rng(6)
     layers = rand_layers(rng, 8)
@@ -106,16 +65,22 @@ def test_sweep_links_backend_passthrough():
                          get_device("edge-server-a100"),
                          link_bw=1e8, input_bytes=1e5)
     bws = np.geomspace(1e5, 1e9, 16)
-    assert_plans_equal(dec.sweep_links(layers, env, bws),
-                       dec.sweep_links(layers, env, bws, backend="jax"))
+    ref = dec.sweep_links(layers, env, bws)
+    got = dec.sweep_links(layers, env, bws, backend="pallas")
+    assert np.array_equal(ref.splits, got.splits)
+    for f in PLAN_FIELDS[1:]:
+        np.testing.assert_allclose(getattr(got, f), getattr(ref, f),
+                                   rtol=1e-5, atol=1e-12, err_msg=f)
 
 
 # --------------------------------------------------------------------------
 # Pallas kernel: within f32 tolerance, chosen splits near-optimal
 # --------------------------------------------------------------------------
-def assert_pallas_close(layers, envs, *, cost=None, rtol=1e-5):
-    ref = decide_ref(layers, envs, cost=cost)
-    got = dec.decide_all(layers, envs, cost=cost, backend="pallas")
+def assert_pallas_close(layers, envs, efficiency=off.DEFAULT_EFFICIENCY,
+                        *, cost=None, rtol=1e-5):
+    ref = decide_ref(layers, envs, efficiency, cost=cost)
+    got = dec.decide_all(layers, envs, efficiency, cost=cost,
+                         backend="pallas")
     # the split the kernel picked, re-costed exactly in f64, must be
     # within f32-argmin distance of the true optimum...
     ranked_ref = ref.scalar_cost if ref.scalar_cost is not None \
@@ -134,11 +99,28 @@ def assert_pallas_close(layers, envs, *, cost=None, rtol=1e-5):
                                    rtol=rtol, atol=1e-12, err_msg=f)
 
 
-@pytest.mark.parametrize("trial", range(4))
-def test_pallas_decide_close(trial):
-    rng = np.random.default_rng(10 + trial)
-    assert_pallas_close(rand_layers(rng, int(rng.integers(1, 40))),
-                        rand_envs(rng, int(rng.integers(1, 64))))
+@pytest.mark.parametrize("seed,max_layers,max_envs", [
+    *(pytest.param(10 + t, 40, 64, id=str(t)) for t in range(4)),
+    *(pytest.param(t, 24, 48, id=f"seed{t}") for t in range(8))])
+def test_pallas_decide_close(seed, max_layers, max_envs):
+    rng = np.random.default_rng(seed)
+    assert_pallas_close(rand_layers(rng, int(rng.integers(1, max_layers))),
+                        rand_envs(rng, int(rng.integers(1, max_envs))))
+
+
+def test_pallas_custom_efficiency_close():
+    rng = np.random.default_rng(4)
+    assert_pallas_close(rand_layers(rng, 9), rand_envs(rng, 12), 0.71)
+
+
+@pytest.mark.parametrize("make_cost", [co.AnalyticCost,
+                                       lambda: co.AnalyticCost(0.5),
+                                       composite],
+                         ids=["analytic", "analytic_eff", "composite"])
+def test_pallas_cost_models_close(make_cost):
+    rng = np.random.default_rng(5)
+    assert_pallas_close(rand_layers(rng, 14), rand_envs(rng, 20),
+                        cost=make_cost())
 
 
 def test_pallas_composite_close():
@@ -162,7 +144,24 @@ def test_pallas_multi_block_sweep():
 
 
 # --------------------------------------------------------------------------
-# degenerate shapes: every backend, every entry point
+# the sharded decide on a one-device mesh: bit-for-bit with numpy (f64)
+# --------------------------------------------------------------------------
+def one_device_mesh():
+    import jax
+    return jax.make_mesh((1,), ("env",), devices=jax.devices()[:1])
+
+
+def test_sharded_one_device_bit_for_bit():
+    rng = np.random.default_rng(3)
+    layers = rand_layers(rng, 19)
+    envs = rand_envs(rng, 31)
+    assert_plans_equal(decide_ref(layers, envs),
+                       decide_all_sharded(layers, envs,
+                                          mesh=one_device_mesh()))
+
+
+# --------------------------------------------------------------------------
+# degenerate shapes: every path, every entry point
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("n_layers,n_envs", [(0, 7), (5, 0), (0, 0)])
 def test_transfer_and_latency_degenerate(n_layers, n_envs):
@@ -174,13 +173,22 @@ def test_transfer_and_latency_degenerate(n_layers, n_envs):
     assert np.all(tb[:, -1] == 0.0)              # split == L ships nothing
     lat = dec.latency_matrix(layers, envs)
     assert lat.shape == (n_envs, n_layers + 1)
-    assert np.array_equal(ops.latency_matrix_jax(layers, envs), lat)
     if n_layers == 0 and n_envs:                 # L == 0: only split 0 == L
         assert np.array_equal(tb, np.zeros((n_envs, 1)))
         assert np.array_equal(lat, np.zeros((n_envs, 1)))
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+@pytest.mark.parametrize("n_layers,n_envs", [(0, 7), (5, 0), (0, 0)])
+def test_sharded_decide_degenerate(n_layers, n_envs):
+    rng = np.random.default_rng(30)
+    layers = rand_layers(rng, n_layers)
+    envs = rand_envs(rng, n_envs)
+    assert_plans_equal(decide_ref(layers, envs),
+                       decide_all_sharded(layers, envs,
+                                          mesh=one_device_mesh()))
+
+
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
 @pytest.mark.parametrize("n_layers,n_envs", [(0, 7), (5, 0), (0, 0)])
 def test_decide_all_degenerate(backend, n_layers, n_envs):
     rng = np.random.default_rng(31)
@@ -194,7 +202,7 @@ def test_decide_all_degenerate(backend, n_layers, n_envs):
         assert np.all(plan.total_time_s == 0.0)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
 @pytest.mark.parametrize("n_layers,n_envs", [(0, 4), (3, 0)])
 def test_decide_all_degenerate_composite(backend, n_layers, n_envs):
     rng = np.random.default_rng(32)
@@ -214,14 +222,13 @@ class _HostModel:
         return np.zeros(len(x))
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_host_only_predictor_rejected_on_accelerator(backend):
+def test_host_only_predictor_rejected_on_accelerator():
     rng = np.random.default_rng(40)
     cost = co.PredictorCost(_HostModel(), get_device("pi5-arm"),
                             get_device("edge-server-a100"))
     with pytest.raises(TypeError, match="host-side"):
         dec.decide_all(rand_layers(rng, 4), rand_envs(rng, 3), cost=cost,
-                       backend=backend)
+                       backend="pallas")
 
 
 def test_composite_over_host_only_base_rejected():
@@ -231,36 +238,27 @@ def test_composite_over_host_only_base_rejected():
     rng = np.random.default_rng(41)
     with pytest.raises(TypeError, match="host-side"):
         dec.decide_all(rand_layers(rng, 4), rand_envs(rng, 3), cost=cost,
-                       backend="jax")
+                       backend="pallas")
 
 
-def test_unknown_backend_rejected():
+@pytest.mark.parametrize("backend", ["tpu", "jax"])
+def test_unknown_backend_rejected(backend):
     rng = np.random.default_rng(42)
-    with pytest.raises(ValueError, match="backend"):
+    with pytest.raises(ValueError, match="'numpy' or 'pallas'"):
         dec.decide_all(rand_layers(rng, 2), rand_envs(rng, 2),
-                       backend="tpu")
+                       backend=backend)
 
 
 def test_efficiency_cost_conflict_guard_on_accelerator():
     rng = np.random.default_rng(43)
     with pytest.raises(ValueError, match="efficiency"):
         dec.decide_all(rand_layers(rng, 2), rand_envs(rng, 2), 0.5,
-                       cost=co.AnalyticCost(), backend="jax")
+                       cost=co.AnalyticCost(), backend="pallas")
 
 
 # --------------------------------------------------------------------------
 # hypothesis: backend equivalence over random env grids
 # --------------------------------------------------------------------------
-@settings(max_examples=20, deadline=None)
-@given(st.integers(0, 10_000), st.integers(0, 12), st.integers(0, 24))
-def test_jax_equivalence_property(seed, n_layers, n_envs):
-    rng = np.random.default_rng(seed)
-    layers = rand_layers(rng, n_layers)
-    envs = rand_envs(rng, n_envs)
-    assert_plans_equal(decide_ref(layers, envs),
-                       dec.decide_all(layers, envs, backend="jax"))
-
-
 @pytest.mark.slow
 @settings(max_examples=10, deadline=None)
 @given(st.integers(0, 10_000), st.integers(1, 20), st.integers(1, 16))

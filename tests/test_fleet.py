@@ -470,12 +470,16 @@ def test_pad_envs():
 
 
 def test_decide_all_sharded_single_device(cnn_layers):
-    """On one device the helper must fall back to the jit path and stay
-    bit-for-bit with the numpy reference."""
+    """On one device the helper runs its own sharded program over a
+    one-device mesh, bit-for-bit with the numpy reference."""
+    from repro.sim.fleet import sharded_latency_fn
     env = make_env("walk", 5)
     envs = env.snapshot(np.linspace(1e5, 8e6, 5))
     ref = dec.decide_all(cnn_layers, envs)
+    before = sharded_latency_fn.cache_info()
     out = sim.decide_all_sharded(cnn_layers, envs)
+    after = sharded_latency_fn.cache_info()
+    assert after.hits + after.misses == before.hits + before.misses + 1
     for f in ("splits", "total_time_s", "device_time_s",
               "transfer_time_s", "edge_time_s"):
         np.testing.assert_array_equal(np.asarray(getattr(ref, f)),
@@ -528,11 +532,11 @@ def test_decide_all_sharded_eight_devices():
 
 
 @pytest.mark.slow
-def test_fleet_jax_split_backend_equivalence(cnn_layers):
-    """decide-at-admission under backend='jax': both engines agree with
-    each other and with the numpy backend."""
+def test_fleet_pallas_split_backend_equivalence(cnn_layers):
+    """decide-at-admission under backend='pallas': both engines agree
+    with each other and with the numpy backend."""
     ref = None
-    for backend in ("numpy", "jax"):
+    for backend in ("numpy", "pallas"):
         ev, fl, *_ = run_both(
             n_tasks=10, n_nodes=3, arrival="trace", linkkind="walk",
             policy="min_min", mode="decide", ground_truth=False, seed=2,

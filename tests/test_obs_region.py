@@ -265,7 +265,7 @@ def answers(path, backend, gbt):
 
 
 @pytest.mark.parametrize("path,backend", [
-    ("decide", "numpy"), ("decide", "jax"), ("decide", "pallas"),
+    ("decide", "numpy"), ("decide", "pallas"),
     ("predict", "jax"), ("predict", "pallas")])
 def test_outputs_bit_identical_under_a_profiler_trace(path, backend, gbt,
                                                       tmp_path):

@@ -4,10 +4,9 @@ Pins the tentpole guarantees of the oracle subsystem:
 
   * lowered GBT inference is *bit-for-bit* with the host ensemble (jax)
     and within f32 tolerance (Pallas kernel);
-  * ``decide_all(cost=PredictorCost(...), backend="jax")`` chooses the
-    exact same splits as the numpy backend (bitwise totals for tree
-    models), ``backend="pallas"`` is tolerance-pinned, and neither
-    raises;
+  * ``decide_all(cost=PredictorCost(...), backend="pallas")`` chooses
+    the same splits as the numpy backend on fixed seeds, within f32
+    tolerance, and does not raise;
   * the ``OnlineOracle`` stays *exactly* transparent in a drift-free
     streaming run (placements bit-for-bit vs the oracle-free path), and
     detects + refits away injected drift;
@@ -163,29 +162,11 @@ def decide_fixture(rng, n_layers=24, n_envs=96):
     return layers, envs
 
 
-@pytest.mark.parametrize("family", ["gbt", "ridge", "mlp"])
-def test_predictor_decide_jax_exact_splits(fitted, family):
-    rng = np.random.default_rng(7)
-    layers, envs = decide_fixture(rng)
-    model = fitted[family]
-    ref = dec.decide_all(layers, envs,
-                         cost=co.PredictorCost(model, DEVICE, EDGE))
-    got = dec.decide_all(layers, envs,
-                         cost=co.PredictorCost(model, DEVICE, EDGE),
-                         backend="jax")
-    assert np.array_equal(ref.splits, got.splits)
-    if family in ("gbt", "ridge"):      # f64 all the way: bitwise totals
-        assert np.array_equal(ref.total_time_s, got.total_time_s)
-        assert np.array_equal(ref.device_time_s, got.device_time_s)
-        assert np.array_equal(ref.components, got.components)
-    else:                               # f32 MLP forward: tolerance
-        np.testing.assert_allclose(got.total_time_s, ref.total_time_s,
-                                   rtol=1e-5, atol=1e-12)
-
-
-@pytest.mark.parametrize("family", ["gbt", "ridge", "mlp"])
-def test_predictor_decide_pallas_tolerance(fitted, family):
-    rng = np.random.default_rng(8)
+@pytest.mark.parametrize("family,seed", [
+    *(pytest.param(f, 8, id=f) for f in ("gbt", "ridge", "mlp")),
+    *(pytest.param(f, 7, id=f"{f}-seed7") for f in ("gbt", "ridge", "mlp"))])
+def test_predictor_decide_pallas_tolerance(fitted, family, seed):
+    rng = np.random.default_rng(seed)
     layers, envs = decide_fixture(rng)
     model = fitted[family]
     ref = dec.decide_all(layers, envs,
@@ -196,7 +177,7 @@ def test_predictor_decide_pallas_tolerance(fitted, family):
     # f32 argmin may flip at a genuine near-tie: compare achieved cost
     assert np.all(got.total_time_s <= ref.total_time_s * (1 + 1e-4)
                   + 1e-12)
-    assert np.array_equal(ref.splits, got.splits)   # holds on this seed
+    assert np.array_equal(ref.splits, got.splits)   # holds on these seeds
     np.testing.assert_allclose(got.total_time_s, ref.total_time_s,
                                rtol=1e-4, atol=1e-12)
 
@@ -212,16 +193,15 @@ def test_composite_over_predictor_decides_on_accel(fitted):
             price_per_edge_s=0.1, price_per_gb=0.01, deadline_s=0.05)
 
     ref = dec.decide_all(layers, envs, cost=cost())
-    got = dec.decide_all(layers, envs, cost=cost(), backend="jax")
-    assert np.array_equal(ref.splits, got.splits)
-    assert np.array_equal(ref.components, got.components)
-    assert np.array_equal(ref.scalar_cost, got.scalar_cost)
     pal = dec.decide_all(layers, envs, cost=cost(), backend="pallas")
+    assert np.array_equal(ref.splits, pal.splits)
+    np.testing.assert_allclose(pal.components, ref.components,
+                               rtol=1e-4, atol=1e-12)
     np.testing.assert_allclose(pal.scalar_cost, ref.scalar_cost,
                                rtol=1e-4, atol=1e-12)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
 @pytest.mark.parametrize("n_layers,n_envs", [(0, 4), (3, 0), (0, 0)])
 def test_predictor_decide_degenerate(fitted, backend, n_layers, n_envs):
     rng = np.random.default_rng(10)
@@ -247,9 +227,10 @@ def test_sweep_links_predictor_backend(fitted):
                                                 EDGE))
     got = dec.sweep_links(layers, env, bws,
                           cost=co.PredictorCost(fitted["gbt"], DEVICE,
-                                                EDGE), backend="jax")
+                                                EDGE), backend="pallas")
     assert np.array_equal(ref.splits, got.splits)
-    assert np.array_equal(ref.total_time_s, got.total_time_s)
+    np.testing.assert_allclose(got.total_time_s, ref.total_time_s,
+                               rtol=1e-4, atol=1e-12)
 
 
 # --------------------------------------------------------------------------

@@ -385,16 +385,17 @@ def tail_cost(tail, wait=0.0):
         rtt=WeibullRTT(shape=0.7, scale=0.02, seed=0))
 
 
-def assert_plans_equal(a, b):
-    for f in ("splits", "total_time_s", "device_time_s",
-              "transfer_time_s", "edge_time_s"):
-        assert np.array_equal(getattr(a, f), getattr(b, f)), f
-    assert a.objectives == b.objectives
-    for f in ("components", "scalar_cost"):
-        x, y = getattr(a, f), getattr(b, f)
-        assert (x is None) == (y is None), f
-        if x is not None:
-            assert np.array_equal(x, y), f
+def assert_pallas_close(layers, envs, cost):
+    """The Pallas plan against numpy's at the f32 kernel's tolerance:
+    the same splits, and every f64 re-evaluated field close."""
+    ref = dec.decide_all(layers, envs, cost=cost, backend="numpy")
+    got = dec.decide_all(layers, envs, cost=cost, backend="pallas")
+    assert np.array_equal(ref.splits, got.splits)
+    assert ref.objectives == got.objectives
+    for f in ("total_time_s", "device_time_s", "transfer_time_s",
+              "edge_time_s", "components", "scalar_cost"):
+        np.testing.assert_allclose(getattr(got, f), getattr(ref, f),
+                                   rtol=1e-5, atol=1e-7, err_msg=f)
 
 
 def test_tail_objective_grows_component_column():
@@ -425,14 +426,10 @@ def test_tail_requires_rtt():
 
 
 @pytest.mark.parametrize("tail", ["p99", "cvar"])
-def test_tail_cost_numpy_jax_bit_for_bit(tail):
+def test_tail_cost_numpy_pallas_close(tail):
     rng = np.random.default_rng(17)
     layers, envs = rand_layers(rng, 12), rand_envs(rng, 9)
-    cost = tail_cost(tail)
-    assert_plans_equal(dec.decide_all(layers, envs, cost=cost,
-                                      backend="numpy"),
-                       dec.decide_all(layers, envs, cost=cost,
-                                      backend="jax"))
+    assert_pallas_close(layers, envs, tail_cost(tail))
 
 
 def test_queue_aware_cost_bumps_latency_only():
@@ -462,30 +459,22 @@ def test_queue_aware_cost_live_pool_state():
 
 
 @pytest.mark.parametrize("tail", [None, "p99"])
-def test_queue_aware_cost_numpy_jax_bit_for_bit(tail):
+def test_queue_aware_cost_numpy_pallas_close(tail):
     rng = np.random.default_rng(23)
     layers, envs = rand_layers(rng, 10), rand_envs(rng, 7)
     base = tail_cost(tail) if tail else co.CompositeCost(
         weights={"latency_s": 1.0, "energy_j": 0.05, "price": 1.0},
         price_per_edge_s=0.1, price_per_gb=0.01, deadline_s=0.05)
-    qa = co.QueueAwareCost(base=base, wait_s=0.1)
-    assert_plans_equal(dec.decide_all(layers, envs, cost=qa,
-                                      backend="numpy"),
-                       dec.decide_all(layers, envs, cost=qa,
-                                      backend="jax"))
+    assert_pallas_close(layers, envs,
+                        co.QueueAwareCost(base=base, wait_s=0.1))
 
 
 def test_queue_aware_cost_pallas_close():
     rng = np.random.default_rng(31)
     layers, envs = rand_layers(rng, 9), rand_envs(rng, 6)
-    qa = co.QueueAwareCost(base=tail_cost("p99"), wait_s=0.05)
-    ref = dec.decide_all(layers, envs, cost=qa, backend="numpy")
-    got = dec.decide_all(layers, envs, cost=qa, backend="pallas")
-    assert np.array_equal(ref.splits, got.splits)
-    for f in ("total_time_s", "device_time_s", "transfer_time_s",
-              "edge_time_s"):
-        np.testing.assert_allclose(getattr(got, f), getattr(ref, f),
-                                   rtol=1e-5, atol=1e-7)
+    assert_pallas_close(layers, envs,
+                        co.QueueAwareCost(base=tail_cost("p99"),
+                                          wait_s=0.05))
 
 
 def test_queue_aware_task_matrix_adds_node_waits():
