@@ -30,7 +30,7 @@ from repro.core.offload import DEFAULT_EFFICIENCY, LayerCost
 from repro.obs.trace import region
 
 #: the kernel's env and split block sizes
-BLOCK_E = 256
+BLOCK_E = 32768
 BLOCK_S = 128
 
 

@@ -9,6 +9,7 @@ where it cannot be described the tests skip.
 """
 import functools
 import os
+import re
 
 import pytest
 
@@ -66,17 +67,33 @@ def _traced_kernels(text: str) -> list[str]:
             if ln.startswith("%tpu_custom_call") and "custom-call(" in ln]
 
 
-def test_decide_split_kernel_compiles(one_chip, no_compile_cache):
-    """16384 envs × L+1 = 1025 splits at the ops block sizes."""
+#: (envs, splits) of the decide kernel: the smoke's sweep, the
+#: ``edge-metro.sweep`` cell's users at its fewest and most splits, and an
+#: admission
+DECIDE_SHAPES = {"smoke": (16384, 1025), "sweep3": (1 << 20, 3),
+                 "sweep34": (1 << 20, 34), "admit": (1, 34)}
+#: an env column relaid for the kernel, one env a row: ``f32[E,1]``
+ENV_COLUMN_COPY = re.compile(r"= f32\[\d+,1\]\S* copy\(")
+
+
+@pytest.mark.parametrize("shape", sorted(DECIDE_SHAPES))
+def test_decide_split_kernel_compiles(one_chip, no_compile_cache, shape):
+    """The decide kernel at ``DECIDE_SHAPES[shape]`` and the ops block
+    sizes: the env columns enter as they are, with no relayout copy and
+    no temp beyond a few tiles."""
+    n_envs, n_splits = DECIDE_SHAPES[shape]
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
                             sharding=one_chip)
     fn = functools.partial(decide_split_kernel, block_e=BLOCK_E,
                            block_s=BLOCK_S, interpret=False)
-    text = _hlo(fn, *[f32((1025,))] * 3, *[f32((16384,))] * 7,
-                f32((SPEC_LEN,)))
-    assert "tpu_custom_call" in text
+    compiled = jax.jit(fn).lower(*[f32((n_splits,))] * 3,
+                                 *[f32((n_envs,))] * 7,
+                                 f32((SPEC_LEN,))).compile()
+    text = compiled.as_text()
     kernels = _traced_kernels(text)
     assert len(kernels) == 1 and " = (s32[" in kernels[0]
+    assert not ENV_COLUMN_COPY.search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 #: per-level node counts of the compiled layouts: a full depth-7 tree

@@ -15,6 +15,8 @@ from repro.core import costs as co
 from repro.core import decisions as dec
 from repro.core import offload as off
 from repro.hw import EDGE_DEVICES, get_device
+from repro.kernels.decide_split import kernel as dk
+from repro.kernels.decide_split.ops import BLOCK_E, BLOCK_S
 from repro.kernels.decide_split.ref import decide_ref, latency_matrix_ref
 from repro.sim import decide_all_sharded
 
@@ -141,6 +143,113 @@ def test_pallas_multi_block_sweep():
     layers = rand_layers(rng, 300)               # 301 splits -> 3 blocks
     envs = rand_envs(rng, 13)                    # pads to block_e
     assert_pallas_close(layers, envs)
+
+
+# --------------------------------------------------------------------------
+# the kernel itself: bit for bit with its formulas in np.float32
+# --------------------------------------------------------------------------
+def kernel_operands(n_envs, n_splits, objective, seed, ties=False):
+    """Split rows and env columns spanning orders of magnitude, so that
+    the optimum falls on every kind of split.  With ``ties`` each pair of
+    splits ``(2k, 2k + 1)`` below ``L`` costs the same to the bit.
+
+    Off the TPU the kernel runs through XLA's CPU compiler, which turns a
+    division by a constant into a product and fuses ``a * b + c`` into
+    one rounding.  So every factor of a product is a power of two (the
+    weights, prices, energy rates) and every byte count a whole GB times
+    one (``ship / 1e9`` exact): each product is then exact, and both
+    roundings of each formula agree with numpy's."""
+    rng = np.random.default_rng(seed)
+
+    def log_uniform(lo, hi, n=n_envs):
+        return 10.0 ** rng.uniform(lo, hi, n)
+    # device layer times rising, edge layer times falling: interior optima
+    dcum = np.cumsum(np.sort(log_uniform(-4, -1, n_splits)))
+    ecum = np.cumsum(np.sort(log_uniform(-5, -2, n_splits))[::-1])
+    def gb_pow2(n):
+        return 1e9 * 2.0 ** rng.integers(-20, -3, n)
+    bvec, inp = gb_pow2(n_splits), gb_pow2(n_envs)
+    if ties:
+        dcum, ecum = (np.repeat(x[:(n_splits + 1) // 2], 2)[:n_splits]
+                      for x in (dcum, ecum))
+        bvec[:], inp[:] = 1e9 / 2 ** 14, 1e9 / 2 ** 14
+    dcum[0] = ecum[0] = bvec[0] = bvec[-1] = 0.0
+    envs = (log_uniform(-1, 1), log_uniform(-2, 0), log_uniform(4, 10),
+            rng.uniform(0.0, 0.05, n_envs), inp,
+            2.0 ** rng.integers(0, 4, n_envs), 2.0 ** rng.integers(3, 9, n_envs))
+    params = {"latency": {},
+              "composite": dict(radio_watts=2.0, price_per_edge_s=2 ** -3,
+                                price_per_gb=2 ** -7, deadline_s=0.05)}
+    params["queue"] = dict(params["composite"], queue_wait_s=0.003,
+                           tail_excess_s=0.002, tail_weight=0.5)
+    weights = (1.0, 0.0, 0.0, 0.0) if objective == "latency" \
+        else (1.0, 2 ** -4, 1.0, 0.5)
+    spec = dk.pack_spec(weights, edge_total=float(np.float32(ecum[-1])),
+                        **params[objective])
+    return [np.asarray(x, np.float32)
+            for x in (dcum, ecum, bvec, *envs)] + [spec]
+
+
+def kernel_in_numpy(dcum, ecum, bvec, dev_div, edge_div, bw, lat, inp,
+                    dev_w, edge_w, spec):
+    """The kernel's cost of every split, one split at a time in its own
+    operation order, and a strict-``<`` running argmin: ties keep the
+    earlier split, as ``np.argmin`` does."""
+    f32, zero = np.float32, np.float32(0.0)
+    n = dcum.shape[0]
+    best = np.full(dev_div.shape, np.inf, f32)
+    idx = np.zeros(dev_div.shape, np.int32)
+    bw1 = np.maximum(bw, f32(1.0))
+    for s in range(n):
+        last = s == n - 1
+        dev_t = dcum[s] / dev_div
+        edge_t = (spec[dk.SPEC_ETOT] - ecum[s]) / edge_div
+        ship = np.zeros_like(inp) if last else inp if s == 0 \
+            else np.full_like(inp, bvec[s])
+        xfer = np.zeros_like(lat) if last else lat + ship / bw1
+        total = dev_t + xfer + edge_t
+        energy = dev_t * dev_w + xfer * spec[dk.SPEC_RADIO] \
+            + edge_t * edge_w
+        price = edge_t * spec[dk.SPEC_PPS] \
+            + ship / f32(1e9) * spec[dk.SPEC_PPG]
+        slack = np.maximum(total - spec[dk.SPEC_DEADLINE], zero)
+        lat_col = total + (zero if last else spec[dk.SPEC_WAIT])
+        scal = spec[dk.SPEC_W0] * lat_col + spec[dk.SPEC_W1] * energy \
+            + spec[dk.SPEC_W2] * price + spec[dk.SPEC_W3] * slack
+        scal = scal + spec[dk.SPEC_W4] * (
+            total + (zero if last else spec[dk.SPEC_TEXC]))
+        better = scal < best
+        best = np.where(better, scal, best)
+        idx = np.where(better, np.int32(s), idx)
+    return idx, best
+
+
+@pytest.mark.parametrize("n_envs,n_splits,objective", [
+    (1, 33, "queue"), (127, 2, "composite"), (129, 3, "queue"),
+    (1023, 301, "composite"), (1025, 129, "queue"), (1025, 33, "latency"),
+    (BLOCK_E + 1, 33, "composite")])
+def test_kernel_bit_for_bit_with_its_formulas(n_envs, n_splits, objective):
+    ops = kernel_operands(n_envs, n_splits, objective, n_envs + n_splits)
+    got = dk.decide_split_kernel(*ops, block_e=BLOCK_E, block_s=BLOCK_S,
+                                 interpret=True)
+    want = kernel_in_numpy(*ops)
+    assert [g.shape for g in got] == [(n_envs,)] * 2
+    assert np.array_equal(np.asarray(got[0]), want[0])
+    assert np.array_equal(np.asarray(got[1]), want[1])
+
+
+@pytest.mark.parametrize("objective", ["latency", "queue"])
+def test_kernel_ties_keep_the_earlier_split(objective):
+    """Splits ``2k`` and ``2k + 1`` cost the same to the bit; the kernel
+    returns the even one, and ``L`` (33 splits: the lone last one)."""
+    ops = kernel_operands(1500, 33, objective, 9, ties=True)
+    split, cost = dk.decide_split_kernel(*ops, block_e=BLOCK_E,
+                                         block_s=BLOCK_S, interpret=True)
+    split = np.asarray(split)
+    want = kernel_in_numpy(*ops)
+    assert np.array_equal(split, want[0])
+    assert np.array_equal(np.asarray(cost), want[1])
+    assert np.all(split % 2 == 0) and len(np.unique(split)) > 2
 
 
 # --------------------------------------------------------------------------
